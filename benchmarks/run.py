@@ -21,8 +21,10 @@ from __future__ import annotations
 import argparse
 import inspect
 import json
+import sys
 
 from repro.core import engine as engine_mod
+from repro.launch import compile_cache
 
 from . import (common, fleet, index_cost, kernels_bench, lcr_bench,
                queries, recovery, scalability, serving, synthetic_sweeps,
@@ -88,6 +90,7 @@ def collect(scale: str, only: str = "", backends: list | None = None,
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default="smoke",
                     choices=sorted(common.SCALES))
@@ -114,6 +117,9 @@ def main() -> None:
         with open(args.json, "w") as f:
             json.dump(records, f, indent=1)
         print(f"# wrote {len(records)} records to {args.json}")
+    failed = [r["name"] for r in records if r["name"].endswith("/ERROR")]
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

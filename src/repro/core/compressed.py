@@ -194,7 +194,14 @@ class BlockCompressed:
     fixpoint kernel: states over ``(br rows × bw words)`` blocks plus a
     compacted pool of the MIXED blocks (bucket-padded so one closure's
     jit signature is stable).  Fields are jax arrays, ready to feed
-    ``repro.kernels.block_sparse`` / its jnp oracle."""
+    ``repro.kernels.block_sparse`` / its jnp oracle.
+
+    The kernel walks the *entry list* ``ent_*``: one entry per non-ZERO
+    block (plus one ZERO entry for each all-ZERO row strip, so every
+    output strip is initialized), sorted by (row-block, word-block) and
+    bucket-padded with inert ZERO entries on the last strip.  It is
+    E-proportional, unlike the ``[MB, KB]`` state grid, so it is what the
+    kernel prefetches into SMEM."""
     shape: tuple                 # dense packed shape (M, Kw)
     nbits: int                   # valid columns (K bits)
     br: int
@@ -205,6 +212,9 @@ class BlockCompressed:
     mix_bi: object               # int32 [P] row-block of pool slot
     mix_bj: object               # int32 [P] word-block of pool slot
     n_mixed: int                 # live pool slots (<= P, rest padding)
+    ent_row: object              # int32 [N] row-block of entry
+    ent_col: object              # int32 [N] word-block of entry
+    ent_meta: object             # int32 [N] slot << 3 | first << 2 | state
 
     @property
     def grid(self) -> tuple:
@@ -213,11 +223,36 @@ class BlockCompressed:
     @property
     def nbytes(self) -> int:
         mb, kb = self.states.shape
-        return -(-mb * kb // 4) + int(self.n_mixed) * self.br * self.bw * 4
+        return (-(-mb * kb // 4) + int(self.n_mixed) * self.br * self.bw * 4
+                + 12 * int(np.shape(self.ent_row)[0]))
 
     @property
     def dense_nbytes(self) -> int:
         return int(self.shape[0] * self.shape[1] * 4)
+
+
+def _entries(states: np.ndarray, slots: np.ndarray) -> dict:
+    """The kernel's entry list (see ``BlockCompressed``) from the state
+    grid: ``first`` marks the entry that initializes its row strip."""
+    import jax.numpy as jnp
+
+    nz = states != ALL_ZERO
+    nz[~nz.any(axis=1), 0] = True        # a ZERO entry for empty strips
+    bi, bj = np.nonzero(nz)              # row-major: sorted by (bi, bj)
+    st = states[bi, bj].astype(np.int32)
+    slot = np.where(st == MIXED, slots[bi, bj], 0).astype(np.int32)
+    first = np.ones(bi.size, dtype=np.int32)
+    first[1:] = bi[1:] != bi[:-1]
+    n = bi.size
+    p = pad_bucket(n, lo=8)
+    row = np.full(p, bi[-1], dtype=np.int32)
+    row[:n] = bi
+    col = np.zeros(p, dtype=np.int32)
+    col[:n] = bj
+    meta = np.zeros(p, dtype=np.int32)   # padding: ZERO, not first
+    meta[:n] = (slot << 3) | (first << 2) | st
+    return dict(ent_row=jnp.asarray(row), ent_col=jnp.asarray(col),
+                ent_meta=jnp.asarray(meta))
 
 
 def compress_blocks(a_packed: np.ndarray, *, br: int = 8, bw: int = 1,
@@ -263,7 +298,7 @@ def compress_blocks(a_packed: np.ndarray, *, br: int = 8, bw: int = 1,
         mix_bj=jnp.asarray(np.concatenate([bj.astype(np.int32),
                                            np.zeros(p - n_mixed,
                                                     np.int32)])),
-        n_mixed=n_mixed)
+        n_mixed=n_mixed, **_entries(states, slots))
 
 
 def _bc_flatten(c: BlockCompressed):
@@ -271,16 +306,19 @@ def _bc_flatten(c: BlockCompressed):
     # under updates, and only shapes/dtypes may key the jit cache — a
     # same-bucket pool must hit the already-compiled fixpoint.
     return ((c.states, c.slots, c.pool, c.mix_bi, c.mix_bj,
-             np.int32(c.n_mixed)),
+             np.int32(c.n_mixed), c.ent_row, c.ent_col, c.ent_meta),
             (c.shape, c.nbits, c.br, c.bw))
 
 
 def _bc_unflatten(aux, children) -> BlockCompressed:
     shape, nbits, br, bw = aux
-    states, slots, pool, mix_bi, mix_bj, n_mixed = children
+    (states, slots, pool, mix_bi, mix_bj, n_mixed, ent_row, ent_col,
+     ent_meta) = children
     return BlockCompressed(shape=shape, nbits=nbits, br=br, bw=bw,
                            states=states, slots=slots, pool=pool,
-                           mix_bi=mix_bi, mix_bj=mix_bj, n_mixed=n_mixed)
+                           mix_bi=mix_bi, mix_bj=mix_bj, n_mixed=n_mixed,
+                           ent_row=ent_row, ent_col=ent_col,
+                           ent_meta=ent_meta)
 
 
 # Pytree registration lets jitted fixpoints close over the block operand
@@ -359,7 +397,7 @@ def patch_blocks(comp: BlockCompressed, rows: np.ndarray,
         mix_bj=jnp.asarray(np.concatenate([bj.astype(np.int32),
                                            np.zeros(p - n_mixed,
                                                     np.int32)])),
-        n_mixed=n_mixed)
+        n_mixed=n_mixed, **_entries(states, slots))
 
 
 def decompress_blocks(comp: BlockCompressed) -> np.ndarray:

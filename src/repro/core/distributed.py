@@ -51,11 +51,6 @@ from . import tdr_build as build_mod
 from . import tdr_query as query_mod
 from .graph import Graph
 
-try:  # jax>=0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 
 def _pad_to(x: np.ndarray, n: int, axis: int = 0, fill=0) -> np.ndarray:
     pad = n - x.shape[axis]
@@ -160,10 +155,12 @@ def distributed_closure(graph: Graph, seed_words: np.ndarray, mesh: Mesh,
     iters = max_iters or v_pad
     spec = P(axes)
 
-    # check_rep=False: jax's replication checker has no rule for the
+    # check_vma=False: the varying-manual-axes checker is off for the
     # converged while_loop (the psum'd changed flag is replicated by
-    # construction — every device sees the same reduction)
-    @functools.partial(shard_map, mesh=mesh, check_rep=False,
+    # construction — every device sees the same reduction).  jit: an
+    # eager shard_map dispatches op by op on every device.
+    @jax.jit
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=(spec, spec, spec, spec), out_specs=spec)
     def run(rows_s, local_s, remote_s, valid_s):
         rows_l = rows_s[0]
@@ -242,9 +239,9 @@ def build_index(graph: Graph, cfg: "build_mod.TDRConfig | None" = None, *,
     spec = P(axes)
     null_j = jnp.asarray(null_w)
 
-    # check_rep=False: see distributed_closure (while_loop has no
-    # replication rule in this jax version)
-    @functools.partial(shard_map, mesh=mesh, check_rep=False,
+    # check_vma=False and jit: see distributed_closure
+    @jax.jit
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=(spec,) * 11, out_specs=(spec,) * 7)
     def run(rows_s, leaf_s, g_s, floc_s, frem_s, fok_s, flab_s, fway_s,
             rloc_s, rrem_s, rok_s):
@@ -374,10 +371,11 @@ def filter_cascade_sharded(index: "build_mod.TDRIndex",
     spec_j = P(axes)
     k = index.cfg.k
 
-    # check_rep=False: the replication checker has no rule for the
-    # pallas_call the cascade's fused way filter lowers to
+    # check_vma=False: the varying-manual-axes checker has no rule for
+    # the pallas_call the cascade's fused way filter lowers to
+    @jax.jit
     @functools.partial(
-        shard_map, mesh=mesh, check_rep=False,
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(spec_j,) * 4 + (P(),) * 12, out_specs=spec_j)
     def run(u, v, req_w, forb_w, null_w, vtx_packed, h_vtx, h_lab, v_vtx,
             v_lab, n_out, n_in, sat_out, sat_in, push, pop):
@@ -431,7 +429,7 @@ def lower_distributed_closure(mesh: Mesh, v_global: int, e_max: int,
     spec = P(axes)
     sharding = NamedSharding(mesh, spec)
 
-    @functools.partial(shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec, spec), out_specs=spec)
     def run(rows_s, local_s, remote_s, valid_s):
         rows_l = rows_s[0]
@@ -486,7 +484,7 @@ def lower_distributed_closure_2d(mesh: Mesh, v_global: int, e_max: int,
     sh_e = NamedSharding(mesh2, P("vtx", None))
 
     @functools.partial(
-        shard_map, mesh=mesh2,
+        jax.shard_map, mesh=mesh2,
         in_specs=(P("vtx", None, "word"), P("vtx", None), P("vtx", None),
                   P("vtx", None)),
         out_specs=P("vtx", None, "word"))

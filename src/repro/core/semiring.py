@@ -39,9 +39,10 @@ import jax.numpy as jnp
 
 from . import bitset
 
-#: saturation cap for COUNT: 2^15 - 1.  With E <= 2^16 corridor edges a
-#: per-round segment_sum accumulates at most 2*cap per edge pair, i.e.
-#: 2^16 * 2^16 < 2^32, so uint32 lane sums cannot wrap before the clamp.
+#: saturation cap for COUNT: 2^15 - 1.  A per-round segment_sum adds at
+#: most ``cap`` per in-edge of a vertex, so below 2^17 in-edges per vertex
+#: (checked by ``tdr_query.count_routes``) uint32 lane sums cannot wrap
+#: before the clamp.
 COUNT_CAP = (1 << 15) - 1
 
 

@@ -86,6 +86,9 @@ from .tdr_build import TDRIndex, _null_words
 FALSE, TRUE, UNKNOWN = 0, 1, 2
 
 _FULL = jnp.uint32(0xFFFFFFFF)
+# largest per-edge RPQ transition table ([E', J, q_u] uint32) gathered once
+# per query; larger tables are re-gathered per NFA state in each push
+_RPQ_TABLE_BYTES = 1 << 28
 
 EXACT_MODES = ("auto", "compact", "full", "legacy")
 
@@ -1381,6 +1384,21 @@ def _edge_dist_ops(lab, req_labels, forb_raw_w, max_m: int,
     return allow, sh
 
 
+def _flip_states(rows, sh, n_states: int):
+    """``rows[..., s ^ sh]`` along the trailing subset-state axis, where
+    ``sh`` (broadcast against ``rows``) is 0 or one subset bit.  A static
+    select per bit, not ``take_along_axis``: its int32 index plane has a
+    minor dim of 1, which the TPU's (8, 128) tiling pads 32x — 16 GiB at
+    E=2^20 edges × 32 jobs."""
+    s_idx = np.arange(n_states)
+    out = rows
+    bit = 1
+    while bit < n_states:
+        out = jnp.where(sh == bit, rows[..., s_idx ^ bit], out)
+        bit <<= 1
+    return out
+
+
 def _dist_meet(df, db, full_mask, best, n_states: int):
     """best[j] = min over vertices x and state pairs (s1, s2) with
     ``s1 | s2 == full_mask[j]`` of ``df[x,j,s1] + db[x,j,s2]`` — the
@@ -1454,8 +1472,7 @@ def _dist_bidi(su, sv, req_labels, forb_raw_w, full_mask, sub_src,
 
     def push(dist, gat, scat):
         rows = dist[gat]                                        # [E, J, S]
-        alt = jnp.take_along_axis(rows, s_idx[None, None, :] ^ shT,
-                                  axis=2)
+        alt = _flip_states(rows, shT, n_states)
         ok = ((s_idx[None, None, :] & shT) == shT) & allowT
         val = jnp.where(ok, jnp.minimum(rows, alt), inf)
         val = val + (val < inf).astype(jnp.uint16)   # saturating +1
@@ -1501,8 +1518,7 @@ def _dist_bidi_matmul(su, sv, req_labels, forb_raw_w, full_mask, adj_rev,
                 adj_c, flat, mode, sr=DIST16)[:v_p].reshape(
                     v_p, j_n, n_states)
             shc = sh_c[None, :, None]
-            alt = jnp.take_along_axis(y, s_idx[None, None, :] ^ shc,
-                                      axis=2)
+            alt = _flip_states(y, shc, n_states)
             ok = (((s_idx[None, None, :] & shc) == shc)
                   & allow_c[None, :, None])
             return jnp.minimum(upd, jnp.where(ok, jnp.minimum(y, alt),
@@ -1556,7 +1572,7 @@ def _dist_forward_parents(su, req_labels, forb_raw_w, sub_src, sub_dst,
     def body(st):
         d, par, _, it = st
         rows = d[sub_src]                                       # [E, S]
-        alt = jnp.take_along_axis(rows, s_idx[None, :] ^ sh, axis=1)
+        alt = _flip_states(rows, sh, n_states)
         ok = ((s_idx[None, :] & sh) == sh) & allow
         val = jnp.where(ok, jnp.minimum(rows, alt), inf)
         val = val + (val < inf).astype(jnp.uint16)
@@ -1602,8 +1618,7 @@ def _count_forward(su, sv, req_labels, forb_raw_w, full_mask, sub_src,
     def body(_, st):
         w, total = st
         rows = w[sub_src]                                       # [E, J, S]
-        alt = jnp.take_along_axis(rows, s_idx[None, None, :] ^ shT,
-                                  axis=2)
+        alt = _flip_states(rows, shT, n_states)
         contrib = rows + jnp.where(shT > 0, alt, 0)
         ok = ((s_idx[None, None, :] & shT) == shT) & allowT
         val = jnp.where(ok, jnp.minimum(contrib, capv), jnp.uint32(0))
@@ -1921,10 +1936,13 @@ def count_routes(index: TDRIndex, u: int, v: int, p: pat.Pattern,
                      jnp.asarray(plan.forb_raw_w),
                      jnp.asarray(plan.full_mask))
     ch = _kind_chunk(index, ex, plan, dev, jobs, exact_mode)
-    if ch.src.shape[0] * cap >= 1 << 32:
+    # a round's segment_sum adds at most ``cap`` per edge into its target:
+    # the widest in-degree bounds the uint32 accumulator before the clamp
+    max_in = int(np.bincount(ch.dst[ch.evalid]).max(initial=0))
+    if max_in * cap >= 1 << 32:
         raise ValueError(
-            f"cap={cap} with {ch.src.shape[0]} edges could wrap the "
-            "uint32 count accumulator; lower the cap")
+            f"cap={cap} with in-degree {max_in} could wrap the uint32 "
+            "count accumulator; lower the cap")
     total = _count_forward(
         jnp.asarray(ch.su), jnp.asarray(ch.sv),
         jnp.asarray(plan.req_labels[:, :m_eff]),
@@ -2010,17 +2028,25 @@ def rpq_rows(index: TDRIndex, r, max_m: int = 4,
     return rows
 
 
-def _nfa_apply(masks, tab_e, q_u: int = 32):
-    """Union of ``tab_e[..., q]`` over the set bits q of ``masks`` — one
-    NFA step applied to a packed state-subset plane.  Static ``q_u``-way
-    unroll (the chunk's NFAs use only states < q_u, so higher bits are
-    provably never set); linearity over union (δ(S₁∪S₂, a) = δ(S₁,a) ∪
-    δ(S₂,a)) is what lets the push below OR-gather neighbours *before*
-    applying the transition table."""
+def _nfa_apply(masks, tab_q, q_u: int = 32, rolled: bool = False):
+    """Union of ``tab_q(q)`` over the set bits q of ``masks`` — one NFA
+    step applied to a packed state-subset plane (``tab_q(q)`` is the
+    transition row of state q, broadcastable against ``masks``).  A
+    ``q_u``-step loop (the chunk's NFAs use only states < q_u, so higher
+    bits are provably never set): a static unroll, or with ``rolled`` a
+    ``fori_loop`` so that one state's rows are live at a time.  Linearity
+    over union (δ(S₁∪S₂, a) = δ(S₁,a) ∪ δ(S₂,a)) is what lets the push
+    below OR-gather neighbours *before* applying the transition table."""
+    if rolled:
+        def body(q, out):
+            hit = ((masks >> q.astype(jnp.uint32)) & jnp.uint32(1)) != 0
+            return out | jnp.where(hit, tab_q(q), jnp.uint32(0))
+
+        return jax.lax.fori_loop(0, q_u, body, jnp.zeros_like(masks))
     out = jnp.zeros_like(masks)
     for q in range(q_u):
         hit = ((masks >> q) & jnp.uint32(1)) != 0
-        out = out | jnp.where(hit, tab_e[..., q], jnp.uint32(0))
+        out = out | jnp.where(hit, tab_q(q), jnp.uint32(0))
     return out
 
 
@@ -2053,8 +2079,26 @@ def _rpq_bidi(su, sv, tabs, rtabs, accept, sub_src, sub_dst, sub_lab,
     iota = jnp.arange(q_n)
     f0 = jnp.zeros((v_p, q_n), jnp.uint32).at[su, iota].set(jnp.uint32(1))
     b0 = jnp.zeros((v_p, q_n), jnp.uint32).at[sv, iota].set(accept)
-    tab_e = jnp.transpose(tabs[:, sub_lab, :q_u], (1, 0, 2))  # [E',J,q_u]
-    rtab_e = jnp.transpose(rtabs[:, sub_lab, :q_u], (1, 0, 2))
+    # per-edge tables [E', J, q_u] are gathered once per query when they
+    # fit the cap; above it (4 GiB at E'=2^20 edges and 32 jobs) each
+    # push re-gathers one state's rows at a time
+    rolled = sub_lab.shape[0] * q_n * q_u * 4 > _RPQ_TABLE_BYTES
+    if rolled:
+        def tab_e(q):
+            return tabs[:, sub_lab, q].T                      # [E', J]
+
+        def rtab_e(q):
+            return rtabs[:, sub_lab, q].T
+    else:
+        tab_all = jnp.transpose(tabs[:, sub_lab, :q_u], (1, 0, 2))
+        rtab_all = jnp.transpose(rtabs[:, sub_lab, :q_u], (1, 0, 2))
+
+        def tab_e(q):
+            return tab_all[..., q]                            # [E', J]
+
+        def rtab_e(q):
+            return rtab_all[..., q]
+
     ev = evalid[:, None]
     cor_w = jnp.full((v_p, q_n), _FULL)
 
@@ -2068,7 +2112,7 @@ def _rpq_bidi(su, sv, tabs, rtabs, accept, sub_src, sub_dst, sub_lab,
         return out
 
     def push(frontier, gat, te, scat, ids):
-        val = _nfa_apply(frontier[gat], te, q_u)             # [E', J]
+        val = _nfa_apply(frontier[gat], te, q_u, rolled)     # [E', J]
         if ids is None:
             val = jnp.where(ev, val, jnp.uint32(0))
             return bitset.segment_or_words(val, scat, num_segments=v_p,
@@ -2117,7 +2161,8 @@ def _rpq_bidi_matmul(su, sv, tabs, rtabs, accept, adj_rev, adj_fwd,
         def body(upd, operand):
             adj_c, tab_c = operand                  # [V', Kw], [J, 32]
             y = engine_mod._matmul_rows(adj_c, frontier, mode)[:v_p]
-            return upd | _nfa_apply(y, tab_c[None, :, :], q_u), None
+            return upd | _nfa_apply(y, lambda q: tab_c[None, :, q],
+                                    q_u), None
         upd, _ = jax.lax.scan(body, jnp.zeros_like(frontier),
                               (adj_set, tab_set))
         return upd

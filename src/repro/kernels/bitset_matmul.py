@@ -1,30 +1,43 @@
-"""Boolean-OR-semiring bit-matmul Pallas kernel.
+"""Semiring bit-matmul Pallas kernel over a packed adjacency bit-matrix.
 
 This is the compute hot-spot of the TPU-adapted TDR engine: one fixpoint
 round of the closure build and one round of product-graph frontier expansion
 are both
 
-    out[i, w] = OR_j ( A[i, j]  AND  X[j, w] )
+    out[i, w] = (+)_j ( A[i, j]  (x)  X[j, w] )
 
 with ``A`` a packed adjacency bit-matrix (bit j of row i = edge i→j) and
-``X`` packed reachability bitsets (32 graph columns per uint32 lane).  The
-kernel runs on the VPU: each (TI, TW) tile accumulates TK selected-row ORs
-consumed 32 columns at a time straight from the packed adjacency words
-(see ``_kernel`` for the two inner forms), i.e. TI·TK·TW word-ops per tile
-at 32 useful graph-bits per op — the arithmetic shape of a matmul without
-an MXU contraction (OR is not ⊕ the MXU supports).  ``repro.kernels.ops`` also exposes an MXU variant that
-unpacks to bf16 and thresholds a real matmul — see ARCHITECTURE.md
-("Kernel lowerings") for the roofline comparison.
+``X`` one carrier lane per element: packed reachability bitsets (32 graph
+columns per uint32, (+) = OR) for the boolean carrier, or one semiring lane
+(uint8/16/32) for the distance and count carriers ((+) = min or saturating
+sum).  The kernel runs on the VPU: each adjacency bit is widened to an
+all-ones/all-zeros lane mask that gates one row of ``X`` into the
+accumulator — the arithmetic shape of a matmul without an MXU contraction
+(OR is not a (+) the MXU supports).  ``repro.kernels.ops`` also exposes an
+MXU variant that unpacks to bf16 and thresholds a real matmul — see
+ARCHITECTURE.md ("Kernel lowerings") for the roofline comparison.
 
 Both the index-build closure fixpoint and the query-side product-graph
 expansion dispatch here when ``repro.core.engine`` selects the ``pallas``
 backend (interpret mode off-TPU); see ARCHITECTURE.md for the layering.
 
-Tiling: grid (M/TI, W/TW, K/TK); K is the innermost ("arbitrary") axis so
-the output tile stays resident in VMEM while adjacency/frontier tiles
-stream through.  VMEM per step = TI·TK/32·4 + TK·TW·4 + TI·TW·4 bytes
-(defaults 128·128·4 ≈ 64 KiB + 2 KiB) — far under the ~16 MiB v5e VMEM,
-leaving room for double-buffered pipelining.
+Tiling: grid (M/TI, W/TW, Kw/TKW); the word axis is innermost
+("arbitrary") so the output tile stays resident in VMEM while adjacency and
+carrier tiles stream through.  Every block keeps TPU-legal trailing dims:
+the adjacency block is (TI, TKW) words with TKW = 128 lanes (the word axis
+is zero-padded to a multiple of it), the carrier block (TKW·32, TW) and
+the output block (TI, TW) with TW = 128 lanes (or the whole lane axis).
+VMEM per step at the defaults: 64 KiB of adjacency + 2 MiB of carrier
+rows (lane-padded) + the output tile, double-buffered — well under the
+16 MiB scoped VMEM of a v5e core.
+
+Inside a step, a ``fori_loop`` walks the block's real (unpadded) adjacency
+words: a dynamic lane rotation brings word ``wk`` of every row to lane 0,
+and its 32 bits gate the 32 carrier rows ``wk·32 .. wk·32+31`` (one
+aligned dynamic sublane slice).  All arithmetic is int32 (Mosaic has no
+unsigned min/reduction): lanes enter as the int32 bit pattern of their
+uint32 value, with the sign bit flipped for ``min`` so signed order
+equals unsigned order.
 """
 from __future__ import annotations
 
@@ -36,156 +49,105 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 WORD = 32
-
-# jax renamed the TPU compiler-params container across releases
-_CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                   or getattr(pltpu, "TPUCompilerParams"))
+LANES = 128
+_SIGN = 0x80000000
 
 
-def _kernel(a_ref, x_ref, o_ref, *, tk: int):
-    """One grid step: o[TI,TW] |= OR_j in TK (a_bit[i,j] & x[j,:]).
-
-    Word-parallel bit-plane formulation: adjacency columns are consumed
-    32 at a time straight from the packed words — ``0 - bit`` wraps a
-    0/1 lane to an all-zeros/all-ones uint32 mask that gates a full
-    ``[TI, TW]`` sheet of ``x`` into the accumulator.  The loop is a
-    static unroll, not the former serial ``fori_loop`` of per-column
-    dynamic slices, so the compiler sees one flat associative
-    accumulation chain over the tile and fuses it into a single
-    vectorized pass (measured 3–10× per round in interpret mode)."""
-    k_step = pl.program_id(2)
-
-    @pl.when(k_step == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    a_words = a_ref[...]                       # [TI, TK//32] uint32
-    x = x_ref[...]                             # [TK, TW]     uint32
-
-    acc = jnp.zeros_like(o_ref[...])
-    for wk in range(tk // WORD):               # static unroll over words
-        col = a_words[:, wk]
-        for b in range(WORD):                  # ...and their 32 lanes
-            sel = jnp.uint32(0) - ((col >> jnp.uint32(b)) & 1)
-            acc |= sel[:, None] & x[wk * WORD + b][None, :]
-    o_ref[...] |= acc
+def to_keys(x: jax.Array, op: str) -> jax.Array:
+    """Carrier lanes -> the int32 keys the kernels compute on."""
+    u = x.astype(jnp.uint32)
+    if op == "min":
+        u = u ^ jnp.uint32(_SIGN)
+    return jax.lax.bitcast_convert_type(u, jnp.int32)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("ti", "tk", "tw", "interpret"))
-def bitset_matmul(a_packed: jax.Array, x: jax.Array, *, ti: int = 128,
-                  tk: int = 128, tw: int = 128,
-                  interpret: bool = False) -> jax.Array:
-    """``OR_j (A[i,j] & X[j,:])`` over packed uint32 operands.
-
-    Args:
-      a_packed: uint32 [M, K//32] adjacency bit-rows.
-      x:        uint32 [K, W] packed bitsets.
-    Returns:
-      uint32 [M, W].
-    """
-    m, kw = a_packed.shape
-    k, w = x.shape
-    assert kw * WORD == k, (a_packed.shape, x.shape)
-    ti = min(ti, m) or 1
-    tk = min(tk, k) or WORD
-    tk = max(WORD, (tk // WORD) * WORD)
-    tw = min(tw, w) or 1
-
-    m_pad = -(-m // ti) * ti
-    k_pad = -(-k // tk) * tk
-    w_pad = -(-w // tw) * tw
-    a_p = jnp.pad(a_packed, ((0, m_pad - m), (0, (k_pad - k) // WORD)))
-    x_p = jnp.pad(x, ((0, k_pad - k), (0, w_pad - w)))
-
-    grid = (m_pad // ti, w_pad // tw, k_pad // tk)
-    out = pl.pallas_call(
-        functools.partial(_kernel, tk=tk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ti, tk // WORD), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tk, tw), lambda i, j, kk: (kk, j)),
-        ],
-        out_specs=pl.BlockSpec((ti, tw), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, w_pad), jnp.uint32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(a_p, x_p)
-    return out[:m, :w]
+def from_keys(k: jax.Array, op: str, dtype) -> jax.Array:
+    """Inverse of ``to_keys`` (back to the carrier dtype)."""
+    u = jax.lax.bitcast_convert_type(k, jnp.uint32)
+    if op == "min":
+        u = u ^ jnp.uint32(_SIGN)
+    return u.astype(dtype)
 
 
-# ---------------------------------------------------------------------------
-# lane-width-generic semiring variant
-# ---------------------------------------------------------------------------
-# Same streaming structure as ``_kernel`` — adjacency consumed 32 columns
-# per packed word, a 0/1 bit wrapped to an all-ones lane mask — but the
-# carrier ``x`` holds one semiring lane per element (uint8/uint16/uint32)
-# instead of 32 packed graph bits, and the accumulation is the semiring
-# combine:
-#
-#   or :  acc |= sel & x[j]            (identity 0)
-#   min:  acc  = min(acc, x[j] | ~sel) (non-selected lanes become
-#                                       dtype-max = INF; identity INF)
-#   sum:  acc  = min(acc + (sel & x[j]), cap)
-#                                      (identity 0; the per-step clamp is
-#                                       exact — saturating add of
-#                                       non-negative values is associative)
-#
-# All three forms are branch-free: selection is the same mask trick, with
-# ``x | ~sel`` turning a de-selected lane into the min-identity.
-
-_ACC_INIT = {"or": lambda dt: jnp.zeros((), dt),
-             "min": lambda dt: jnp.array(jnp.iinfo(dt).max, dt),
-             "sum": lambda dt: jnp.zeros((), dt)}
+def key_ident(op: str, dtype) -> int:
+    """The (+)-identity of ``op`` over ``dtype`` lanes, as an int32 key:
+    0 for or/sum, dtype-max (INF) for min."""
+    if op != "min":
+        return 0
+    key = int(jnp.iinfo(dtype).max) ^ _SIGN
+    return key - (1 << 32) if key & _SIGN else key
 
 
-def _lane_kernel(a_ref, x_ref, o_ref, *, tk: int, op: str, cap: int):
-    k_step = pl.program_id(2)
-    dt = o_ref.dtype
-    ident = _ACC_INIT[op](dt)
-
-    @pl.when(k_step == 0)
-    def _init():
-        o_ref[...] = jnp.full_like(o_ref, ident)
-
-    a_words = a_ref[...]                       # [TI, TK//32] uint32
-    x = x_ref[...]                             # [TK, TW]     carrier lanes
-
-    acc = jnp.full_like(o_ref[...], ident)
-    for wk in range(tk // WORD):               # static unroll over words
-        col = a_words[:, wk]
-        for b in range(WORD):
-            bit = ((col >> jnp.uint32(b)) & 1).astype(dt)
-            sel = (jnp.zeros((), dt) - bit)[:, None]     # 0x00.. / 0xFF..
-            row = x[wk * WORD + b][None, :]
-            if op == "or":
-                acc |= sel & row
-            elif op == "min":
-                acc = jnp.minimum(acc, row | ~sel)
-            else:
-                acc = jnp.minimum(acc + (sel & row), jnp.array(cap, dt))
+def combine(op: str, cap: int, acc, upd):
+    """Fold a partial (+)-reduction into ``acc`` (int32 keys)."""
     if op == "or":
-        o_ref[...] |= acc
-    elif op == "min":
-        o_ref[...] = jnp.minimum(o_ref[...], acc)
-    else:
-        o_ref[...] = jnp.minimum(o_ref[...] + acc, jnp.array(cap, dt))
+        return acc | upd
+    if op == "min":
+        return jnp.minimum(acc, upd)
+    return jnp.minimum(acc + upd, jnp.int32(cap))
+
+
+def contract(a: jax.Array, x_ref, acc: jax.Array, n_words, *, op: str,
+             cap: int, ident: int) -> jax.Array:
+    """``acc (+)= (+)_j a_bit[:, j] (x) x_ref[j, :]`` over the first
+    ``n_words`` words of ``a`` ([R, words] int32 adjacency words; the
+    rest is zero padding, never visited; ``x_ref`` holds the ``words·32``
+    carrier rows).  Shared by the dense and block-sparse kernels."""
+    words = a.shape[1]
+    ident_v = jnp.int32(ident)
+
+    def word(wk, acc):
+        # lane wk of every row -> lane 0 (a static lane slice after a
+        # dynamic rotate: Mosaic has no dynamic lane indexing)
+        col = a if words == 1 else pltpu.roll(a, (words - wk) % words, 1)
+        col = col[:, :1]                                   # [R, 1]
+        xw = x_ref[pl.ds(pl.multiple_of(wk * WORD, WORD), WORD), :]
+        for b in range(WORD):
+            sel = jnp.int32(0) - ((col >> b) & 1)          # 0 / all-ones
+            row = xw[b:b + 1, :]                           # [1, TW]
+            if op == "or":
+                acc = acc | (sel & row)
+            elif op == "min":
+                acc = jnp.minimum(acc, (row & sel) | (ident_v & ~sel))
+            else:
+                acc = jnp.minimum(acc + (sel & row), jnp.int32(cap))
+        return acc
+
+    return jax.lax.fori_loop(0, n_words, word, acc)
+
+
+def _kernel(a_ref, x_ref, o_ref, *, kw: int, op: str, cap: int,
+            ident: int):
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        o_ref[...] = jnp.full(o_ref.shape, ident, jnp.int32)
+
+    tkw = a_ref.shape[1]
+    acc = contract(a_ref[...], x_ref,
+                   jnp.full(o_ref.shape, ident, jnp.int32),
+                   jnp.minimum(tkw, kw - kk * tkw),
+                   op=op, cap=cap, ident=ident)
+    o_ref[...] = combine(op, cap, o_ref[...], acc)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("op", "cap", "ti", "tk", "tw",
                                     "interpret"))
 def lane_matmul(a_packed: jax.Array, x: jax.Array, *, op: str,
-                cap: int = 0, ti: int = 128, tk: int = 128, tw: int = 128,
-                interpret: bool = False) -> jax.Array:
+                cap: int = 0, ti: int = 128, tk: int = LANES * WORD,
+                tw: int = LANES, interpret: bool = False) -> jax.Array:
     """``(+)_j (A[i,j] (x) X[j,:])`` — packed-bit adjacency, lane carrier.
 
     Args:
       a_packed: uint32 [M, K//32] adjacency bit-rows (bit j of row i).
       x:        [K, W] semiring carrier lanes (uint8/uint16/uint32).
       op:       lane combine — "or", "min" (identity dtype-max) or
-                "sum" (saturating at ``cap``).
+                "sum" (saturating at ``cap``; lanes must be <= ``cap``).
+      ti, tk, tw: row, adjacency-column (bits) and lane tile.  The
+                defaults give TPU-legal blocks for every shape; other
+                values are for interpret-mode tiling tests.
     Returns:
       [M, W] in ``x.dtype``.  Padding rows of ``a_packed`` have no bits
       set, so pad lanes never leak into real outputs regardless of op.
@@ -195,28 +157,44 @@ def lane_matmul(a_packed: jax.Array, x: jax.Array, *, op: str,
     k, w = x.shape
     assert kw * WORD == k, (a_packed.shape, x.shape)
     ti = min(ti, m) or 1
-    tk = min(tk, k) or WORD
-    tk = max(WORD, (tk // WORD) * WORD)
+    tkw = max(1, tk // WORD)
     tw = min(tw, w) or 1
 
     m_pad = -(-m // ti) * ti
-    k_pad = -(-k // tk) * tk
+    kw_pad = -(-kw // tkw) * tkw
     w_pad = -(-w // tw) * tw
-    a_p = jnp.pad(a_packed, ((0, m_pad - m), (0, (k_pad - k) // WORD)))
-    x_p = jnp.pad(x, ((0, k_pad - k), (0, w_pad - w)))
+    a_p = jnp.pad(jax.lax.bitcast_convert_type(a_packed, jnp.int32),
+                  ((0, m_pad - m), (0, kw_pad - kw)))
+    x_p = jnp.pad(to_keys(x, op), ((0, (kw_pad - kw) * WORD),
+                                   (0, w_pad - w)))
+    ident = key_ident(op, x.dtype)
 
-    grid = (m_pad // ti, w_pad // tw, k_pad // tk)
     out = pl.pallas_call(
-        functools.partial(_lane_kernel, tk=tk, op=op, cap=cap),
-        grid=grid,
+        functools.partial(_kernel, kw=kw, op=op, cap=cap, ident=ident),
+        grid=(m_pad // ti, w_pad // tw, kw_pad // tkw),
         in_specs=[
-            pl.BlockSpec((ti, tk // WORD), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((tk, tw), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((ti, tkw), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((tkw * WORD, tw), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((ti, tw), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, w_pad), x.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((m_pad, w_pad), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a_p, x_p)
-    return out[:m, :w]
+    return from_keys(out[:m, :w], op, x.dtype)
+
+
+def bitset_matmul(a_packed: jax.Array, x: jax.Array, *, ti: int = 128,
+                  tk: int = LANES * WORD, tw: int = LANES,
+                  interpret: bool = False) -> jax.Array:
+    """``OR_j (A[i,j] & X[j,:])`` over packed uint32 operands.
+
+    Args:
+      a_packed: uint32 [M, K//32] adjacency bit-rows.
+      x:        uint32 [K, W] packed bitsets.
+    Returns:
+      uint32 [M, W].
+    """
+    return lane_matmul(a_packed, x, op="or", ti=ti, tk=tk, tw=tw,
+                       interpret=interpret)

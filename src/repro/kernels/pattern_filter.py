@@ -12,10 +12,18 @@ PCR queries are screened per second:
     reached(j,g,ℓ)  =  vbits[j] ⊆ V_vtx[j,g,ℓ]
 
 Inputs arrive pre-gathered per job (the ``u``-row gather is a plain XLA op
-outside the kernel), so every ref is contiguous and the kernel is a pure
-streaming elementwise+reduce pass: bytes dominate, arithmetic intensity
-≈ 1 op/byte — firmly memory-bound, which is why fusing the whole cascade
-into one pass (instead of 5 separate XLA reductions) is the win.
+outside the kernel), so the kernel is a pure streaming elementwise pass:
+bytes dominate, arithmetic intensity ≈ 1 op/byte — firmly memory-bound,
+which is why fusing the whole cascade into one pass (instead of 5 separate
+XLA reductions) is the win.
+
+Layout: the job axis is the lane axis.  The wrapper flattens every operand
+to ``[rows, J]`` (rows = (way, level, word) in row-major order), so each
+word of each way is one lane-dense row and every reduction over words,
+ways or levels is a static unroll of row-wise ANDs/ORs — no lane
+reductions, no cumulative scans, nothing Mosaic cannot lower.  The result
+is an int32 ``[G, J]`` 0/1 plane (Mosaic stores no bool blocks), turned
+back into ``bool [J, G]`` outside the kernel.
 """
 from __future__ import annotations
 
@@ -26,31 +34,39 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(hv_ref, hl_ref, vv_ref, vl_ref, vbits_ref, req_ref, forb_ref,
-            null_ref, o_ref, *, k: int):
-    hv = hv_ref[...]        # [TJ, G, Wv]
-    hl = hl_ref[...]        # [TJ, G, Wl]
-    vv = vv_ref[...]        # [TJ, G, k, Wv]
-    vl = vl_ref[...]        # [TJ, G, k, Wl]
-    vbits = vbits_ref[...]  # [TJ, Wv]
-    req = req_ref[...]      # [TJ, Wl]
-    forb = forb_ref[...]    # [TJ, Wl]
-    null = null_ref[...]    # [1, Wl]
+def _kernel(hv_ref, hl_ref, vv_ref, vl_ref, vb_ref, rq_ref, fb_ref, o_ref,
+            *, g: int, k: int, wv: int, wl: int):
+    def row(ref, r):
+        return ref[r:r + 1, :]                                  # [1, TJ]
 
-    has_tgt = jnp.all((hv & vbits[:, None, :]) == vbits[:, None, :], axis=-1)
-    has_req = jnp.all((hl & req[:, None, :]) == req[:, None, :], axis=-1)
+    def contains(ref, base, sub_ref, n):
+        ok = None
+        for w in range(n):
+            s = row(sub_ref, w)
+            c = (row(ref, base + w) & s) == s
+            ok = c if ok is None else ok & c
+        return ok
 
-    real = vl & ~forb[:, None, None, :] & ~null[None, None, :, :]
-    blocked = jnp.all(real == 0, axis=-1)                        # [TJ,G,k]
-    reached = jnp.all((vv & vbits[:, None, None, :])
-                      == vbits[:, None, None, :], axis=-1)       # [TJ,G,k]
-    reached_upto = jnp.cumsum(reached.astype(jnp.int32), axis=-1) > 0
-    not_before = jnp.concatenate(
-        [jnp.ones_like(reached_upto[..., :1]), ~reached_upto[..., :-1]],
-        axis=-1)
-    refuted = jnp.any(blocked & not_before, axis=-1)             # [TJ, G]
+    def blocked(base):
+        ok = None
+        for w in range(wl):
+            c = (row(vl_ref, base + w) & ~row(fb_ref, w)) == 0
+            ok = c if ok is None else ok & c
+        return ok
 
-    o_ref[...] = (has_tgt & has_req & ~refuted)
+    for gi in range(g):
+        ok = (contains(hv_ref, gi * wv, vb_ref, wv)
+              & contains(hl_ref, gi * wl, rq_ref, wl))
+        # static prefix-OR over the k levels: level l refutes the way only
+        # if the target was not already reached at some level l' < l
+        seen = None
+        for l in range(k):
+            lvl = gi * k + l
+            blk = blocked(lvl * wl)
+            ok = ok & ~(blk if seen is None else blk & ~seen)
+            reached = contains(vv_ref, lvl * wv, vb_ref, wv)
+            seen = reached if seen is None else seen | reached
+        o_ref[gi:gi + 1, :] = jnp.where(ok, jnp.int32(1), jnp.int32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("tj", "interpret"))
@@ -63,33 +79,28 @@ def way_filter(h_vtx: jax.Array, h_lab: jax.Array, v_vtx: jax.Array,
     All inputs packed uint32, already gathered per job:
       h_vtx [J,G,Wv] h_lab [J,G,Wl] v_vtx [J,G,k,Wv] v_lab [J,G,k,Wl]
       vbits [J,Wv] req/forb [J,Wl] null_plane [Wl]
+    ``tj`` is the job (lane) tile: a multiple of 128, or at least J.
     """
     j, g, wv = h_vtx.shape
     k = v_vtx.shape[2]
     wl = h_lab.shape[-1]
-    tj = max(1, min(tj, j))
+    tj = j if j <= tj else tj
     j_pad = -(-j // tj) * tj
 
-    def padj(x):
-        return jnp.pad(x, ((0, j_pad - j),) + ((0, 0),) * (x.ndim - 1))
+    def lanes(x):   # [J, ...] -> int32 [rows, J_pad], the job axis on lanes
+        x = jax.lax.bitcast_convert_type(x.reshape(j, -1), jnp.int32)
+        return jnp.pad(x.T, ((0, 0), (0, j_pad - j)))
 
-    grid = (j_pad // tj,)
+    # NULL is masked exactly like a forbidden label, so fold it in here
+    args = [lanes(h_vtx), lanes(h_lab), lanes(v_vtx), lanes(v_lab),
+            lanes(vbits), lanes(req), lanes(forb | null_plane[None, :])]
     out = pl.pallas_call(
-        functools.partial(_kernel, k=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tj, g, wv), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tj, g, wl), lambda i: (i, 0, 0)),
-            pl.BlockSpec((tj, g, k, wv), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((tj, g, k, wl), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((tj, wv), lambda i: (i, 0)),
-            pl.BlockSpec((tj, wl), lambda i: (i, 0)),
-            pl.BlockSpec((tj, wl), lambda i: (i, 0)),
-            pl.BlockSpec((1, wl), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tj, g), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((j_pad, g), jnp.bool_),
+        functools.partial(_kernel, g=g, k=k, wv=wv, wl=wl),
+        grid=(j_pad // tj,),
+        in_specs=[pl.BlockSpec((a.shape[0], tj), lambda i: (0, i))
+                  for a in args],
+        out_specs=pl.BlockSpec((g, tj), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((g, j_pad), jnp.int32),
         interpret=interpret,
-    )(padj(h_vtx), padj(h_lab), padj(v_vtx), padj(v_lab), padj(vbits),
-      padj(req), padj(forb), null_plane[None, :])
-    return out[:j]
+    )(*args)
+    return out[:, :j].T != 0

@@ -24,7 +24,7 @@ def _kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tr", "interpret"))
-def popcount_rows(words: jax.Array, *, tr: int = 512,
+def popcount_rows(words: jax.Array, *, tr: int = 1024,
                   interpret: bool = False) -> jax.Array:
     """Popcount over the trailing axis of uint32 [N, W] -> int32 [N]."""
     n, w = words.shape

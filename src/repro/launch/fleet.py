@@ -50,13 +50,14 @@ import sys
 import threading
 import time
 
+import jax
 import numpy as np
 
 from repro.core import deltalog as deltalog_mod
 from repro.core import pattern as pat
 from repro.core import rpq as rpq_mod
 from repro.core import snapshot as snapshot_mod
-from repro.launch import serve
+from repro.launch import compile_cache, serve
 
 
 class ReplicaDied(RuntimeError):
@@ -208,8 +209,10 @@ def replica_worker(directory: str, backend: str | None, poll_s: float,
 
     hb = threading.Thread(target=heartbeat, daemon=True)
     hb.start()
+    # the platform lets the parent refuse a replica that came up on
+    # another backend (a child cannot share the parent's accelerator)
     emit({"ev": "ready", "lsn": server.stats.applied_lsn,
-          "pid": os.getpid()})
+          "pid": os.getpid(), "platform": jax.devices()[0].platform})
     try:
         for line in sys.stdin:
             line = line.strip()
@@ -253,6 +256,7 @@ class Replica:
         self.lsn = -1            # last heartbeat/ready/answer LSN
         self.queued = 0
         self.ready = False
+        self.platform: str | None = None   # jax platform, from "ready"
         self.alive = True
         self.last_hb = time.monotonic()
         self.pending: dict[int, object] = {}   # id -> router request
@@ -295,6 +299,7 @@ class Replica:
                     self.lsn = max(self.lsn, int(msg.get("lsn", -1)))
                     self.queued = int(msg.get("queued", 0))
                     if ev == "ready":
+                        self.platform = msg.get("platform")
                         self.ready = True
                 if self._on_event is not None:
                     self._on_event(self, msg)
@@ -384,6 +389,16 @@ class Fleet:
                     raise TimeoutError(
                         f"{r.name} not ready within {ready_timeout_s}s")
                 time.sleep(0.05)
+        # a replica is its own process: on an accelerator it gets no
+        # device the parent already holds and may come up on the CPU
+        want = jax.devices()[0].platform
+        wrong = [f"{r.name}={r.platform}" for r in self._members
+                 if r.ready and r.platform != want]
+        if wrong:
+            self.stop()
+            raise RuntimeError(
+                f"replicas report platform {', '.join(wrong)}, the "
+                f"parent runs on {want}")
         self._monitor = threading.Thread(target=self._monitor_loop,
                                          name="fleet-monitor",
                                          daemon=True)
@@ -502,6 +517,7 @@ class Fleet:
 
 # ------------------------------------------------------------ CLI worker
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--replica", metavar="DIR", required=True,
                     help="shared fleet store to follow")

@@ -95,6 +95,7 @@ from repro.core import pattern as pat
 from repro.core import rpq as rpq_mod
 from repro.core import snapshot as snapshot_mod
 from repro.core import tdr_build, tdr_query
+from repro.launch import compile_cache
 
 LOG_NAME = "deltas.wal"
 _SNAP_RE = re.compile(r"snapshot-(\d+)\.tdr")
@@ -1238,6 +1239,7 @@ def mixed_pool(g, n: int, seed: int = 0):
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         description="TDR query-serving demo: closed-loop clients against "
                     "the micro-batching scheduler")

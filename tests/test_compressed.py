@@ -13,12 +13,8 @@ stay equal to fresh compressions of its dense planes after any update.
 import numpy as np
 import pytest
 
-try:
-    import hypothesis as hp
-    import hypothesis.strategies as st
-except ImportError:  # clean container: vendored fallback (see _minihyp.py)
-    import _minihyp as hp
-    st = hp.strategies
+import hypothesis as hp
+import hypothesis.strategies as st
 
 import jax.numpy as jnp
 
@@ -164,6 +160,59 @@ def test_blocksparse_closure_bit_identical_pallas():
             # default policy routes interpret-mode closures dense: no
             # new sparse-kernel trace may appear
             assert ops.KERNEL_INVOCATIONS["block_sparse_matmul"] == n1
+
+
+def _lane_brute(a_bits, x, op, cap):
+    """``(+)_j a[i, j] (x) x[j]`` by brute force (numpy)."""
+    sel = a_bits[:, :, None]
+    if op == "or":
+        return np.bitwise_or.reduce(np.where(sel, x[None], 0), axis=1)
+    if op == "min":
+        ident = np.iinfo(x.dtype).max
+        return np.where(sel, x[None], ident).min(axis=1).astype(x.dtype)
+    tot = np.where(sel, x[None].astype(np.uint64), 0).sum(axis=1)
+    return np.minimum(tot, cap).astype(x.dtype)
+
+
+@pytest.mark.parametrize("op,dtype", [("or", np.uint32), ("min", np.uint16),
+                                      ("min", np.uint32), ("sum", np.uint32)])
+@pytest.mark.parametrize("chunk", [4, 8192], ids=["chunked", "one-call"])
+def test_block_sparse_kernel_matches_brute_force(op, dtype, chunk,
+                                                 monkeypatch):
+    """The interpret-mode block-sparse kernel over its entry list equals
+    the jnp oracle and a brute force, on ZERO / ONE / MIXED blocks with
+    row and column tails and a dead X k-block, both in one call and with
+    the entry list cut into chunks of 4 (strips straddle the cuts)."""
+    from repro.kernels import block_sparse
+
+    rng = np.random.default_rng(11)
+    m, kw, nbits, br = 45, 3, 90, 8
+    masks = C._valid_masks(kw, nbits)
+    a = rng.integers(0, 2 ** 32, size=(m, kw), dtype=np.uint32)
+    kind = rng.integers(0, 3, size=(-(-m // br), kw))   # 0 zero, 1 one
+    for bi in range(kind.shape[0]):
+        rows = slice(bi * br, (bi + 1) * br)
+        for bj in range(kw):
+            a[rows, bj] = {0: 0, 1: masks[bj]}.get(kind[bi, bj],
+                                                  a[rows, bj])
+    a &= masks[None, :]
+    c = C.compress_blocks(a, br=br, bw=1, nbits=nbits)
+    assert {0, 1, 2} <= set(np.asarray(c.states).ravel().tolist())
+    cap = 1000
+    hi = 16 if op == "sum" else cap         # sums both under and at cap
+    x = rng.integers(0, hi, size=(nbits, 5)).astype(dtype)
+    ident = np.iinfo(dtype).max if op == "min" else 0
+    x[32:64] = ident                                    # dead k-block 1
+    a_bits = np.unpackbits(a.view(np.uint8), axis=1,
+                           bitorder="little").astype(bool)[:, :nbits]
+    want = _lane_brute(a_bits, x, op, cap)
+    monkeypatch.setattr(block_sparse, "CHUNK", chunk)
+    got = block_sparse.block_sparse_lane_matmul(
+        c, jnp.asarray(x), op=op, cap=cap, interpret=True)
+    ref = block_sparse.block_sparse_lane_matmul_ref(c, jnp.asarray(x),
+                                                    op=op, cap=cap)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(ref), want)
 
 
 @pytest.mark.parametrize("kind", ["er", "pa"])

@@ -3,12 +3,8 @@ planner/executor vs the DFS oracle, and kernel load-bearing-ness."""
 import numpy as np
 import pytest
 
-try:
-    import hypothesis as hp
-    import hypothesis.strategies as st
-except ImportError:  # clean container: vendored fallback (see _minihyp.py)
-    import _minihyp as hp
-    st = hp.strategies
+import hypothesis as hp
+import hypothesis.strategies as st
 
 import jax.numpy as jnp
 

@@ -343,3 +343,37 @@ def test_fleet_subprocess_sigkill_smoke():
         env=env, capture_output=True, text=True, timeout=1200)
     assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     assert "fleet check OK" in r.stdout
+
+
+class _FakeReplica:
+    """Stands in for a replica process that reported ``ready``."""
+
+    def __init__(self, name, platform):
+        self.name, self.platform = name, platform
+        self.ready = self.alive = True
+        self.stopped = False
+
+    def stop(self):
+        self.stopped = True
+
+
+@pytest.mark.parametrize("other", [False, True], ids=["same", "other"])
+def test_fleet_start_refuses_replica_on_other_platform(other, monkeypatch,
+                                                       tmp_path):
+    """A replica is its own process: on an accelerator it may come up on
+    the CPU.  ``Fleet.start`` compares each replica's reported platform
+    with the parent's and refuses a mismatch."""
+    import jax
+    want = jax.devices()[0].platform
+    reps = [_FakeReplica("replica-1", want),
+            _FakeReplica("replica-2", "elsewhere" if other else want)]
+    fl = fleet_mod.Fleet(str(tmp_path), n=2)
+    monkeypatch.setattr(fl, "_spawn_locked", lambda: reps.pop(0))
+    monkeypatch.setattr(fl, "_monitor_loop", lambda: None)
+    if other:
+        with pytest.raises(RuntimeError, match="replica-2=elsewhere"):
+            fl.start(ready_timeout_s=5)
+        assert all(r.stopped for r in fl._members)
+    else:
+        fl.start(ready_timeout_s=5)
+        fl.stop()

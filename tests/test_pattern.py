@@ -1,12 +1,8 @@
 """Pattern AST / parser / DNF compiler tests (+ hypothesis properties)."""
 import pytest
 
-try:
-    import hypothesis as hp
-    import hypothesis.strategies as st
-except ImportError:  # clean container: vendored fallback (see _minihyp.py)
-    import _minihyp as hp
-    st = hp.strategies
+import hypothesis as hp
+import hypothesis.strategies as st
 
 from repro.core import pattern as pat
 

@@ -22,12 +22,8 @@ import itertools
 import numpy as np
 import pytest
 
-try:
-    import hypothesis as hp
-    import hypothesis.strategies as st
-except ImportError:  # clean container: vendored fallback (see _minihyp.py)
-    import _minihyp as hp
-    st = hp.strategies
+import hypothesis as hp
+import hypothesis.strategies as st
 
 import _qgen
 from repro.core import dfs_baseline, graph as G, pattern as pat, rpq
@@ -36,7 +32,6 @@ from repro.core import tdr_build, tdr_query
 CFG = tdr_build.TDRConfig(vtx_bits=64, g_max=4, k=3)
 
 # built lazily at module scope so @given property tests can share them
-# (minihyp wrappers take no arguments — fixtures and strategies can't mix)
 _CACHE: dict = {}
 
 
@@ -298,6 +293,25 @@ def test_exact_modes_agree():
         assert got.tolist() == want, mode
     with pytest.raises(ValueError, match="exact_mode"):
         tdr_query.rpq_batch(idx, qs, exact_mode="legacy")
+
+
+def test_rolled_table_path_vs_oracle(monkeypatch):
+    """Above ``_RPQ_TABLE_BYTES`` the segment executor re-gathers one NFA
+    state's per-edge rows per push instead of hoisting the whole table:
+    forced here with a zero cap, it must give the oracle's answers."""
+    gi = 0
+    g = _graphs()[gi]
+    idx = _index(gi, "segment")
+    qs = _case_pool(gi, seed=9, n=24)
+    want = [dfs_baseline.answer_rpq(g, u, v, r) for u, v, r in qs]
+    monkeypatch.setattr(tdr_query, "_RPQ_TABLE_BYTES", 0)
+    tdr_query._rpq_bidi.clear_cache()      # the cap is read at trace time
+    try:
+        got = tdr_query.rpq_batch(idx, qs, backend="segment",
+                                  exact_mode="full")
+    finally:
+        tdr_query._rpq_bidi.clear_cache()
+    assert got.tolist() == want
 
 
 def test_answer_mixed_routes_rpq():

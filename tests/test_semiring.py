@@ -287,6 +287,33 @@ def test_count_saturates_at_cap():
     assert sat >= 1  # the cap actually bites somewhere
 
 
+@pytest.mark.parametrize("hub", [False, True], ids=["spread", "hub"])
+def test_count_cap_bounded_by_in_degree(hub):
+    """The uint32 accumulator wraps only if one vertex's in-edges times
+    the cap reach 2^32: a large graph with small in-degrees counts at a
+    cap that edges times cap would overflow; a hub of 64 in-edges at
+    cap 2^26 is refused."""
+    n = 96
+    if hub:
+        edges = [(i, 0, 0) for i in range(1, 65)] + [(0, 1, 0)]
+    else:
+        edges = [(i, (i + d) % n, d % 2) for i in range(n)
+                 for d in (1, 2, 3)]
+    g = G.Graph.from_edges(n, 2, edges)
+    idx = tdr_build.build_index(g)
+    cap = 1 << 26
+    assert g.n_edges * cap >= 1 << 32     # the old edge-count bound
+    if hub:
+        with pytest.raises(ValueError, match="in-degree"):
+            tdr_query.count_routes(idx, 1, 0, pat.label(0), hops=3,
+                                   cap=cap, exact_mode="full")
+        return
+    p = pat.label(1)
+    for u, v in ((0, 5), (3, 3), (10, 40)):
+        assert tdr_query.count_routes(idx, u, v, p, hops=6, cap=cap) == \
+            dfs_baseline.count_routes(g, u, v, p, hops=6, cap=cap)
+
+
 def test_count_rejects_multi_term():
     g = _graphs()[0]
     idx = tdr_build.build_index(g)

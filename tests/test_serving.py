@@ -14,12 +14,8 @@ import threading
 import numpy as np
 import pytest
 
-try:
-    import hypothesis as hp
-    import hypothesis.strategies as st
-except ImportError:  # clean container: vendored fallback (see _minihyp.py)
-    import _minihyp as hp
-    st = hp.strategies
+import hypothesis as hp
+import hypothesis.strategies as st
 
 from repro.core import dfs_baseline, graph as G, pattern as pat
 from repro.core import rpq, tdr_build, tdr_query
@@ -28,8 +24,7 @@ from repro.launch import serve
 CFG = tdr_build.TDRConfig(vtx_bits=64, g_max=4, k=3)
 
 # built lazily at module scope (not a fixture) so the @given property
-# tests can use it too — the minihyp fallback's wrappers take no
-# arguments, so fixtures and strategies cannot mix there
+# tests can use it too
 _CACHE: dict = {}
 
 

@@ -3,12 +3,8 @@ filter soundness, distributed build (hypothesis property tests)."""
 import numpy as np
 import pytest
 
-try:
-    import hypothesis as hp
-    import hypothesis.strategies as st
-except ImportError:  # clean container: vendored fallback (see _minihyp.py)
-    import _minihyp as hp
-    st = hp.strategies
+import hypothesis as hp
+import hypothesis.strategies as st
 
 from repro.core import (dfs_baseline, graph as G, lcr, pattern as pat,
                         tdr_build, tdr_query)

@@ -66,7 +66,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
-import time
 import warnings
 from typing import Any, NamedTuple, Sequence
 
@@ -82,6 +81,7 @@ from . import rpq as rpq_mod
 from . import dfs_baseline as dfs_mod
 from .semiring import COUNT_CAP, DIST16
 from .tdr_build import TDRIndex, _null_words
+from ..utils import spans
 
 FALSE, TRUE, UNKNOWN = 0, 1, 2
 
@@ -152,6 +152,11 @@ class QueryPlan:
             n_queries=self.n_queries, max_m=self.max_m, kinds=self.kinds)
 
 
+#: unread phase-2 round counters a ``QueryStats`` holds before it reads
+#: them all at once (a long-lived server never reads ``exact_rounds``)
+_ROUND_PARTS_CAP = 256
+
+
 @dataclasses.dataclass
 class QueryStats:
     n_queries: int = 0
@@ -167,16 +172,36 @@ class QueryStats:
     corridor_active: int = 0   # Σ |V'| over dispatched phase-2 chunks
     corridor_total: int = 0    # Σ |V|  over dispatched phase-2 chunks
     saturated_chunks: int = 0  # chunks whose probe the summaries answered
-    phase1_s: float = 0.0      # planner + filter cascade wall time
-    phase2_s: float = 0.0      # exact expansion wall time (incl. collect)
-    # device round counters, fetched lazily on first .exact_rounds access
-    # so dispatching chunks never blocks on a per-chunk host sync
+    # wall time of the ``query.plan``, ``query.phase1`` and ``query.phase2``
+    # spans (``repro.utils.spans``): plan compile, the filter cascade, and
+    # exact expansion from dispatch to collected answers
+    plan_s: float = 0.0
+    phase1_s: float = 0.0
+    phase2_s: float = 0.0
+    exact_chunks: int = 0      # phase-2 chunks run (each adds its rounds)
+    # rounds of the chunks already read, plus the device round counters
+    # not yet read: those are fetched on .exact_rounds access (or once
+    # _ROUND_PARTS_CAP pile up), so dispatching never waits on them
+    _rounds: int = dataclasses.field(default=0, repr=False)
     _round_parts: list = dataclasses.field(default_factory=list, repr=False)
+
+    def add_chunk(self, rounds) -> None:
+        """Count one phase-2 chunk and its rounds (a device scalar whose
+        chunk the caller has already collected, so reading it waits on no
+        computation)."""
+        self.exact_chunks += 1
+        self._round_parts.append(rounds)
+        if len(self._round_parts) >= _ROUND_PARTS_CAP:
+            self._fold_rounds()
+
+    def _fold_rounds(self) -> None:
+        parts, self._round_parts = self._round_parts, []
+        self._rounds += int(sum(jax.device_get(parts)))
 
     @property
     def exact_rounds(self) -> int:
-        self._round_parts[:] = [int(r) for r in self._round_parts]
-        return sum(self._round_parts)
+        self._fold_rounds()
+        return self._rounds
 
     @property
     def corridor_occupancy(self) -> float:
@@ -293,50 +318,51 @@ def compile_queries(index: TDRIndex,
     O(n_queries) numpy concatenation.  The optional fourth element is one
     of ``QUERY_KINDS`` (default "bool"); it does not change the plan rows,
     only which executor the driver routes the query to."""
-    cfg = index.cfg
-    wl = bitset.n_words(cfg.lab_bits)
-    wraw = bitset.n_words(max(index.graph.n_labels, 1))
-    kinds = []
-    norm = []
-    for q in queries:
-        kind = q[3] if len(q) > 3 else "bool"
-        if kind not in QUERY_KINDS:
-            raise ValueError(
-                f"unknown query kind {kind!r}; expected one of "
-                f"{QUERY_KINDS}")
-        if kind == "rpq":
-            raise ValueError(
-                "kind='rpq' queries carry a repro.core.rpq AST, not a "
-                "pattern; route them through rpq_batch / answer_mixed")
-        kinds.append(kind)
-        norm.append((q[0], q[1], q[2]))
-    queries = norm
-    rows_per_q = [pattern_rows(index, p, max_m, stats=stats)
-                  for (_, _, p) in queries]
-    counts = np.asarray([r.n_terms for r in rows_per_q], dtype=np.int64)
+    with spans.span("query.plan", stats, "plan_s"):
+        cfg = index.cfg
+        wl = bitset.n_words(cfg.lab_bits)
+        wraw = bitset.n_words(max(index.graph.n_labels, 1))
+        kinds = []
+        norm = []
+        for q in queries:
+            kind = q[3] if len(q) > 3 else "bool"
+            if kind not in QUERY_KINDS:
+                raise ValueError(
+                    f"unknown query kind {kind!r}; expected one of "
+                    f"{QUERY_KINDS}")
+            if kind == "rpq":
+                raise ValueError(
+                    "kind='rpq' queries carry a repro.core.rpq AST, not a "
+                    "pattern; route them through rpq_batch / answer_mixed")
+            kinds.append(kind)
+            norm.append((q[0], q[1], q[2]))
+        queries = norm
+        rows_per_q = [pattern_rows(index, p, max_m, stats=stats)
+                      for (_, _, p) in queries]
+        counts = np.asarray([r.n_terms for r in rows_per_q], dtype=np.int64)
 
-    def cat(name, empty_cols):
-        parts = [getattr(r, name) for r in rows_per_q if r.n_terms]
-        if not parts:
-            dt = np.int32 if name in ("req_labels", "full_mask") else \
-                np.uint32
-            shape = (0,) if name == "full_mask" else (0, empty_cols)
-            return np.zeros(shape, dtype=dt)
-        return np.concatenate(parts)
+        def cat(name, empty_cols):
+            parts = [getattr(r, name) for r in rows_per_q if r.n_terms]
+            if not parts:
+                dt = np.int32 if name in ("req_labels", "full_mask") else \
+                    np.uint32
+                shape = (0,) if name == "full_mask" else (0, empty_cols)
+                return np.zeros(shape, dtype=dt)
+            return np.concatenate(parts)
 
-    uv = np.asarray([(u, v) for (u, v, _) in queries],
-                    dtype=np.int32).reshape(len(queries), 2)
-    qid = np.repeat(np.arange(len(queries), dtype=np.int32), counts)
-    return QueryPlan(
-        qid=qid,
-        u=np.repeat(uv[:, 0], counts),
-        v=np.repeat(uv[:, 1], counts),
-        req_w=cat("req_w", wl), forb_w=cat("forb_w", wl),
-        forb_raw_w=cat("forb_raw_w", wraw),
-        req_labels=cat("req_labels", max_m),
-        full_mask=cat("full_mask", 0),
-        n_queries=len(queries), max_m=max_m,
-        kinds=tuple(kinds) if any(k != "bool" for k in kinds) else ())
+        uv = np.asarray([(u, v) for (u, v, _) in queries],
+                        dtype=np.int32).reshape(len(queries), 2)
+        qid = np.repeat(np.arange(len(queries), dtype=np.int32), counts)
+        return QueryPlan(
+            qid=qid,
+            u=np.repeat(uv[:, 0], counts),
+            v=np.repeat(uv[:, 1], counts),
+            req_w=cat("req_w", wl), forb_w=cat("forb_w", wl),
+            forb_raw_w=cat("forb_raw_w", wraw),
+            req_labels=cat("req_labels", max_m),
+            full_mask=cat("full_mask", 0),
+            n_queries=len(queries), max_m=max_m,
+            kinds=tuple(kinds) if any(k != "bool" for k in kinds) else ())
 
 
 # ----------------------------------------------------------- phase 1 (jit)
@@ -1112,12 +1138,11 @@ def answer_batch(index: TDRIndex,
     their own plans and padding (the serving scheduler) use that entry
     point directly.
     """
-    t0 = time.perf_counter()
     plan = compile_queries(index, queries, max_m=max_m, stats=stats)
     return answer_plan(index, plan, exact_chunk=exact_chunk, stats=stats,
                        filters_only=filters_only, backend=backend,
                        exact_mode=exact_mode, engine_config=engine_config,
-                       mesh=mesh, _t0=t0)
+                       mesh=mesh)
 
 
 def answer_plan(index: TDRIndex, plan: QueryPlan,
@@ -1130,8 +1155,7 @@ def answer_plan(index: TDRIndex, plan: QueryPlan,
                 mesh=None,
                 special_labels: Sequence[int] | None = None,
                 pin_m: int | None = None,
-                pad_lo: int = 16,
-                _t0: float | None = None) -> np.ndarray:
+                pad_lo: int = 16) -> np.ndarray:
     """Answer a compiled ``QueryPlan``.  Returns bool [plan.n_queries].
 
     ``backend``/``engine_config`` select the packed-word engine backend for
@@ -1176,41 +1200,42 @@ def answer_plan(index: TDRIndex, plan: QueryPlan,
             "answer_plan serves kind='bool' plans only; route mixed-kind "
             "batches through answer_mixed (or dist_batch / witness / "
             "count_routes directly)")
-    t0 = _t0 if _t0 is not None else time.perf_counter()
-    eng = index.engine(backend, engine_config)
     stats = stats if stats is not None else QueryStats()
-    stats.n_queries += plan.n_queries
-    stats.n_jobs += plan.n_jobs
-    answers = np.zeros(plan.n_queries, dtype=bool)
-    if plan.n_jobs == 0:
-        return answers
+    with spans.span("query.phase1", stats, "phase1_s"):
+        eng = index.engine(backend, engine_config)
+        stats.n_queries += plan.n_queries
+        stats.n_jobs += plan.n_jobs
+        answers = np.zeros(plan.n_queries, dtype=bool)
+        if plan.n_jobs == 0:
+            return answers
 
-    # pad the job axis onto the bucket grid so jit shapes stay stable
-    # (and, under a mesh, further to a multiple of the device count)
-    plan_p = plan.pad_to(graph_mod.pad_bucket(plan.n_jobs, lo=pad_lo))
-    if mesh is not None:
-        n_dev = mesh.devices.size
-        plan_p = plan_p.pad_to(-(-plan_p.n_jobs // n_dev) * n_dev)
-    pd_u, pd_v = jnp.asarray(plan_p.u), jnp.asarray(plan_p.v)
-    if mesh is not None:
-        from . import distributed as dist_mod  # deferred: imports us back
-        verdict = dist_mod.filter_cascade_sharded(index, plan_p, mesh,
-                                                  eng.kernel_mode)
-    else:
-        sat_out_d, sat_in_d = index.summary_flags_dev()
-        verdict = np.asarray(_filter_cascade(
-            pd_u, pd_v,
-            jnp.asarray(plan_p.req_w), jnp.asarray(plan_p.forb_w),
-            _null_words_dev(index.cfg),
-            index.vtx_packed, index.h_vtx, index.h_lab, index.v_vtx,
-            index.v_lab, index.n_out, index.n_in, sat_out_d, sat_in_d,
-            index.push, index.pop, k=index.cfg.k, mode=eng.kernel_mode))
+        # pad the job axis onto the bucket grid so jit shapes stay stable
+        # (and, under a mesh, further to a multiple of the device count)
+        plan_p = plan.pad_to(graph_mod.pad_bucket(plan.n_jobs, lo=pad_lo))
+        if mesh is not None:
+            n_dev = mesh.devices.size
+            plan_p = plan_p.pad_to(-(-plan_p.n_jobs // n_dev) * n_dev)
+        pd_u, pd_v = jnp.asarray(plan_p.u), jnp.asarray(plan_p.v)
+        if mesh is not None:
+            # deferred: distributed imports this module back
+            from . import distributed as dist_mod
+            verdict = dist_mod.filter_cascade_sharded(index, plan_p, mesh,
+                                                      eng.kernel_mode)
+        else:
+            sat_out_d, sat_in_d = index.summary_flags_dev()
+            verdict = np.asarray(_filter_cascade(
+                pd_u, pd_v,
+                jnp.asarray(plan_p.req_w), jnp.asarray(plan_p.forb_w),
+                _null_words_dev(index.cfg),
+                index.vtx_packed, index.h_vtx, index.h_lab, index.v_vtx,
+                index.v_lab, index.n_out, index.n_in, sat_out_d, sat_in_d,
+                index.push, index.pop, k=index.cfg.k, mode=eng.kernel_mode))
 
-    real = plan_p.qid >= 0
-    stats.filter_false += int(((verdict == FALSE) & real).sum())
-    stats.filter_true += int(((verdict == TRUE) & real).sum())
-    np.logical_or.at(answers, plan_p.qid[(verdict == TRUE) & real], True)
-    stats.phase1_s += time.perf_counter() - t0
+        real = plan_p.qid >= 0
+        stats.filter_false += int(((verdict == FALSE) & real).sum())
+        stats.filter_true += int(((verdict == TRUE) & real).sum())
+        np.logical_or.at(answers, plan_p.qid[(verdict == TRUE) & real],
+                         True)
 
     pending = np.flatnonzero((verdict == UNKNOWN) & real)
     # jobs whose query is already TRUE need no exact work
@@ -1225,108 +1250,113 @@ def answer_plan(index: TDRIndex, plan: QueryPlan,
     if len(pending) == 0:
         return answers
 
-    t1 = time.perf_counter()
-    ex = _executor(index, eng)
-    v_n = index.graph.n_vertices
-    special = ex.special_labels(plan_p, pending)
-    if special_labels is not None:
-        # a serving pin fixes the label-class set (stable operand shapes,
-        # resident adjacency cache); union keeps it sound if traffic ever
-        # needs a label outside the pin
-        special = tuple(sorted(set(int(l) for l in special_labels)
-                               | set(special)))
-    dev = None
-    if exact_mode != "legacy":
-        dev = PlanDevice(pd_u, pd_v, jnp.asarray(plan_p.req_labels),
-                         jnp.asarray(plan_p.forb_raw_w),
-                         jnp.asarray(plan_p.full_mask))
+    with spans.span("query.phase2", stats, "phase2_s"):
+        ex = _executor(index, eng)
+        v_n = index.graph.n_vertices
+        special = ex.special_labels(plan_p, pending)
+        if special_labels is not None:
+            # a serving pin fixes the label-class set (stable operand
+            # shapes, resident adjacency cache); union keeps it sound if
+            # traffic ever needs a label outside the pin
+            special = tuple(sorted(set(int(l) for l in special_labels)
+                                   | set(special)))
+        dev = None
+        if exact_mode != "legacy":
+            dev = PlanDevice(pd_u, pd_v, jnp.asarray(plan_p.req_labels),
+                             jnp.asarray(plan_p.forb_raw_w),
+                             jnp.asarray(plan_p.full_mask))
 
-    # chunk layout + compaction probe: per-job corridor sizes cost one tiny
-    # device round-trip; full [P, V] membership is fetched only for the
-    # jobs of chunks that will actually compact
-    starts = list(range(0, len(pending), exact_chunk))
-    if exact_mode == "legacy" or exact_mode == "full":
-        compact_flags = [False] * len(starts)
-    elif exact_mode == "compact":
-        compact_flags = [True] * len(starts)
-    else:
-        # summary-first probe skip: a chunk whose every job has ALL_ONE
-        # N_out[u] and N_in[v] rows (level-1 summaries of the compressed
-        # planes) has corridor == full V *exactly*, so the probe would
-        # always pick the full-graph path — settle those chunks from the
-        # host flags and probe only the rest (whole chunks, in order, so
-        # ``chunk_union_counts``'s sequential grouping stays aligned)
-        flags = index.summary_flags()
-        jsat = (flags["sat_out"][plan_p.u[pending]]
-                & flags["sat_in"][plan_p.v[pending]])
-        sat_chunks = [bool(jsat[c0:c0 + exact_chunk].all())
-                      for c0 in starts]
-        stats.saturated_chunks += sum(sat_chunks)
-        compact_flags = [False] * len(starts)
-        probe_starts = [c0 for c0, s in zip(starts, sat_chunks) if not s]
-        if probe_starts:
-            probe_jobs = np.concatenate(
-                [pending[c0:c0 + exact_chunk] for c0 in probe_starts])
-            unions = ex.chunk_union_counts(dev, probe_jobs, exact_chunk)
-            for c0, u in zip(probe_starts, unions):
-                compact_flags[c0 // exact_chunk] = (
-                    graph_mod.pad_bucket(int(u), lo=32) < v_n)
-    member = None
-    mem_off = {}
-    if any(compact_flags):
-        cjobs = np.concatenate(
-            [pending[c0:c0 + exact_chunk]
-             for c0, flag in zip(starts, compact_flags) if flag])
-        member = ex.corridor_members(dev, cjobs)
-        off = 0
-        for c0, flag in zip(starts, compact_flags):
-            if flag:
-                n = len(pending[c0:c0 + exact_chunk])
-                mem_off[c0] = (off, off + n)
-                off += n
-
-    # dispatch every chunk, then collect once — no per-chunk host sync.
-    # Under a mesh, *compacted* chunks round-robin over its devices:
-    # their operands (induced subgraph, membership rows) are per-chunk
-    # host data that must transfer anyway, so spreading them is pure
-    # concurrency (dispatch is async).  Full-graph chunks stay on the
-    # lead device, where the V-sized shared operands (index planes,
-    # cached incidence / class adjacency) already live — round-robining
-    # those would re-ship the whole index every chunk.
-    devices = list(mesh.devices.flat) if mesh is not None else [None]
-    results = []
-    rr = 0
-    for c0, flag in zip(starts, compact_flags):
-        jobs = pending[c0:c0 + exact_chunk]
-        real_n = len(jobs)
-        rows = member[slice(*mem_off[c0])] if flag else None
-        if real_n < exact_chunk:   # pad to a stable jit shape
-            jobs = np.concatenate(
-                [jobs, np.full(exact_chunk - real_n, jobs[0], np.int64)])
-            if rows is not None:
-                rows = np.concatenate(
-                    [rows, np.repeat(rows[:1], exact_chunk - real_n,
-                                     axis=0)])
-        dev_i = devices[0] if mesh is None or not flag \
-            else devices[rr % len(devices)]
-        rr += flag
-        if dev_i is None:
-            res = ex.dispatch_chunk(plan_p, dev, jobs, rows, special,
-                                    exact_mode, pin_m)
+        # chunk layout + compaction probe: per-job corridor sizes cost one
+        # tiny device round-trip; full [P, V] membership is fetched only for
+        # the jobs of chunks that will actually compact
+        starts = list(range(0, len(pending), exact_chunk))
+        if exact_mode == "legacy" or exact_mode == "full":
+            compact_flags = [False] * len(starts)
+        elif exact_mode == "compact":
+            compact_flags = [True] * len(starts)
         else:
-            with jax.default_device(dev_i):
-                res = ex.dispatch_chunk(plan_p, dev, jobs, rows, special,
-                                        exact_mode, pin_m)
-        res.real_n = real_n
-        results.append(res)
-    for res in results:
-        reached = np.asarray(res.reached)[:res.real_n]
-        hit = res.jobs[:res.real_n][reached]
-        np.logical_or.at(answers, plan_p.qid[hit], True)
-        stats._round_parts.append(res.rounds)
-        stats.corridor_active += res.n_active
-        stats.corridor_total += res.v_total
-    stats.phase2_s += time.perf_counter() - t1
+            # summary-first probe skip: a chunk whose every job has
+            # ALL_ONE N_out[u] and N_in[v] rows (level-1 summaries of the
+            # compressed planes) has corridor == full V *exactly*, so the
+            # probe would always pick the full-graph path — settle those
+            # chunks from the host flags and probe only the rest (whole
+            # chunks, in order, so ``chunk_union_counts``'s sequential
+            # grouping stays aligned)
+            flags = index.summary_flags()
+            jsat = (flags["sat_out"][plan_p.u[pending]]
+                    & flags["sat_in"][plan_p.v[pending]])
+            sat_chunks = [bool(jsat[c0:c0 + exact_chunk].all())
+                          for c0 in starts]
+            stats.saturated_chunks += sum(sat_chunks)
+            compact_flags = [False] * len(starts)
+            probe_starts = [c0 for c0, s in zip(starts, sat_chunks)
+                            if not s]
+            if probe_starts:
+                probe_jobs = np.concatenate(
+                    [pending[c0:c0 + exact_chunk] for c0 in probe_starts])
+                unions = ex.chunk_union_counts(dev, probe_jobs,
+                                               exact_chunk)
+                for c0, u in zip(probe_starts, unions):
+                    compact_flags[c0 // exact_chunk] = (
+                        graph_mod.pad_bucket(int(u), lo=32) < v_n)
+        member = None
+        mem_off = {}
+        if any(compact_flags):
+            cjobs = np.concatenate(
+                [pending[c0:c0 + exact_chunk]
+                 for c0, flag in zip(starts, compact_flags) if flag])
+            member = ex.corridor_members(dev, cjobs)
+            off = 0
+            for c0, flag in zip(starts, compact_flags):
+                if flag:
+                    n = len(pending[c0:c0 + exact_chunk])
+                    mem_off[c0] = (off, off + n)
+                    off += n
+
+        # dispatch every chunk, then collect once — no per-chunk host sync.
+        # Under a mesh, *compacted* chunks round-robin over its devices:
+        # their operands (induced subgraph, membership rows) are per-chunk
+        # host data that must transfer anyway, so spreading them is pure
+        # concurrency (dispatch is async).  Full-graph chunks stay on the
+        # lead device, where the V-sized shared operands (index planes,
+        # cached incidence / class adjacency) already live —
+        # round-robining those would re-ship the whole index every chunk.
+        devices = list(mesh.devices.flat) if mesh is not None else [None]
+        with spans.span("query.phase2.dispatch"):
+            results = []
+            rr = 0
+            for c0, flag in zip(starts, compact_flags):
+                jobs = pending[c0:c0 + exact_chunk]
+                real_n = len(jobs)
+                rows = member[slice(*mem_off[c0])] if flag else None
+                if real_n < exact_chunk:   # pad to a stable jit shape
+                    jobs = np.concatenate(
+                        [jobs, np.full(exact_chunk - real_n, jobs[0],
+                                       np.int64)])
+                    if rows is not None:
+                        rows = np.concatenate(
+                            [rows, np.repeat(rows[:1],
+                                             exact_chunk - real_n, axis=0)])
+                dev_i = devices[0] if mesh is None or not flag \
+                    else devices[rr % len(devices)]
+                rr += flag
+                if dev_i is None:
+                    res = ex.dispatch_chunk(plan_p, dev, jobs, rows,
+                                            special, exact_mode, pin_m)
+                else:
+                    with jax.default_device(dev_i):
+                        res = ex.dispatch_chunk(plan_p, dev, jobs, rows,
+                                                special, exact_mode, pin_m)
+                res.real_n = real_n
+                results.append(res)
+        with spans.span("query.phase2.collect"):
+            for res in results:
+                reached = np.asarray(res.reached)[:res.real_n]
+                hit = res.jobs[:res.real_n][reached]
+                np.logical_or.at(answers, plan_p.qid[hit], True)
+                stats.add_chunk(res.rounds)
+                stats.corridor_active += res.n_active
+                stats.corridor_total += res.v_total
     return answers
 
 
@@ -1712,92 +1742,91 @@ def dist_batch(index: TDRIndex,
     if exact_mode not in ("auto", "compact", "full"):
         raise ValueError(f"unknown exact_mode {exact_mode!r} for dist; "
                          "expected auto | compact | full")
-    t0 = time.perf_counter()
-    plan = compile_queries(index, queries, max_m=max_m, stats=stats)
-    eng = index.engine(backend, engine_config)
     stats = stats if stats is not None else QueryStats()
-    stats.n_queries += plan.n_queries
-    stats.n_jobs += plan.n_jobs
-    out = np.full(plan.n_queries, -1, np.int64)
-    if plan.n_jobs == 0:
-        return out
-    ex = _executor(index, eng)
-    jobs_all = np.arange(plan.n_jobs)
-    m_eff, n_states = ex.eff_states(plan, jobs_all, pin_m)
-    if n_states > 32:
-        raise ValueError(
-            f"max_m={m_eff} needs {n_states} subset states; the lane "
-            "executor holds at most 32 (max_m <= 5)")
-    dev = PlanDevice(jnp.asarray(plan.u), jnp.asarray(plan.v),
-                     jnp.asarray(plan.req_labels),
-                     jnp.asarray(plan.forb_raw_w),
-                     jnp.asarray(plan.full_mask))
-    best_j = np.full(plan.n_jobs, _DBIG, np.int64)
-    for c0 in range(0, plan.n_jobs, exact_chunk):
-        jobs = jobs_all[c0:c0 + exact_chunk]
-        real_n = len(jobs)
-        if real_n < exact_chunk:   # pad to a stable jit shape
-            jobs = np.concatenate(
-                [jobs, np.full(exact_chunk - real_n, jobs[0])])
-        ch = _kind_chunk(index, ex, plan, dev, jobs, exact_mode)
-        max_rounds = ch.v_p * n_states + 1
-        it_cap = jnp.int32(max_rounds if k is None
-                           else max(-(-int(k) // 2), 0))
-        req = jnp.asarray(plan.req_labels[jobs][:, :m_eff])
-        frw = jnp.asarray(plan.forb_raw_w[jobs])
-        fm = jnp.asarray(plan.full_mask[jobs])
-        su, sv = jnp.asarray(ch.su), jnp.asarray(ch.sv)
-        best = rounds = None
-        if eng.backend == "pallas":
-            special = ex.special_labels(plan, jobs)
-            if special_labels is not None:
-                special = tuple(sorted(
-                    set(int(l) for l in special_labels) | set(special)))
-            kw_b = bitset.n_words(ch.v_p)
-            n_mats = 2 * (len(special) + 1)
-            if n_mats * ch.v_p * kw_b * 4 <= eng.config.max_dense_bytes:
-                class_label = jnp.asarray(
-                    np.asarray(special + (-1,), np.int32))
-                if ch.sub_ids is None:
-                    adj_rev = eng.label_class_adjacency(special,
-                                                        reverse=True)
-                    adj_fwd = eng.label_class_adjacency(special,
-                                                       reverse=False)
-                else:
-                    # padding rows duplicate edge 0: the same bit set
-                    # twice — idempotent in a packed bit-matrix
-                    adj_rev = jnp.asarray(
-                        engine_mod.pack_label_class_edges_np(
-                            ch.src, ch.dst, ch.lab, ch.v_p, special,
-                            reverse=True))
-                    adj_fwd = jnp.asarray(
-                        engine_mod.pack_label_class_edges_np(
-                            ch.src, ch.dst, ch.lab, ch.v_p, special,
-                            reverse=False))
-                best_d, rounds = _dist_bidi_matmul(
-                    su, sv, req, frw, fm, adj_rev, adj_fwd, class_label,
-                    it_cap, n_states=n_states, max_m=m_eff,
-                    max_rounds=max_rounds, mode=eng.matmul_mode)
+    plan = compile_queries(index, queries, max_m=max_m, stats=stats)
+    with spans.span("query.phase2", stats, "phase2_s"):
+        eng = index.engine(backend, engine_config)
+        stats.n_queries += plan.n_queries
+        stats.n_jobs += plan.n_jobs
+        out = np.full(plan.n_queries, -1, np.int64)
+        if plan.n_jobs == 0:
+            return out
+        ex = _executor(index, eng)
+        jobs_all = np.arange(plan.n_jobs)
+        m_eff, n_states = ex.eff_states(plan, jobs_all, pin_m)
+        if n_states > 32:
+            raise ValueError(
+                f"max_m={m_eff} needs {n_states} subset states; the lane "
+                "executor holds at most 32 (max_m <= 5)")
+        dev = PlanDevice(jnp.asarray(plan.u), jnp.asarray(plan.v),
+                         jnp.asarray(plan.req_labels),
+                         jnp.asarray(plan.forb_raw_w),
+                         jnp.asarray(plan.full_mask))
+        best_j = np.full(plan.n_jobs, _DBIG, np.int64)
+        for c0 in range(0, plan.n_jobs, exact_chunk):
+            jobs = jobs_all[c0:c0 + exact_chunk]
+            real_n = len(jobs)
+            if real_n < exact_chunk:   # pad to a stable jit shape
+                jobs = np.concatenate(
+                    [jobs, np.full(exact_chunk - real_n, jobs[0])])
+            ch = _kind_chunk(index, ex, plan, dev, jobs, exact_mode)
+            max_rounds = ch.v_p * n_states + 1
+            it_cap = jnp.int32(max_rounds if k is None
+                               else max(-(-int(k) // 2), 0))
+            req = jnp.asarray(plan.req_labels[jobs][:, :m_eff])
+            frw = jnp.asarray(plan.forb_raw_w[jobs])
+            fm = jnp.asarray(plan.full_mask[jobs])
+            su, sv = jnp.asarray(ch.su), jnp.asarray(ch.sv)
+            best = rounds = None
+            if eng.backend == "pallas":
+                special = ex.special_labels(plan, jobs)
+                if special_labels is not None:
+                    special = tuple(sorted(
+                        set(int(l) for l in special_labels) | set(special)))
+                kw_b = bitset.n_words(ch.v_p)
+                n_mats = 2 * (len(special) + 1)
+                if n_mats * ch.v_p * kw_b * 4 <= eng.config.max_dense_bytes:
+                    class_label = jnp.asarray(
+                        np.asarray(special + (-1,), np.int32))
+                    if ch.sub_ids is None:
+                        adj_rev = eng.label_class_adjacency(special,
+                                                            reverse=True)
+                        adj_fwd = eng.label_class_adjacency(special,
+                                                           reverse=False)
+                    else:
+                        # padding rows duplicate edge 0: the same bit set
+                        # twice — idempotent in a packed bit-matrix
+                        adj_rev = jnp.asarray(
+                            engine_mod.pack_label_class_edges_np(
+                                ch.src, ch.dst, ch.lab, ch.v_p, special,
+                                reverse=True))
+                        adj_fwd = jnp.asarray(
+                            engine_mod.pack_label_class_edges_np(
+                                ch.src, ch.dst, ch.lab, ch.v_p, special,
+                                reverse=False))
+                    best_d, rounds = _dist_bidi_matmul(
+                        su, sv, req, frw, fm, adj_rev, adj_fwd, class_label,
+                        it_cap, n_states=n_states, max_m=m_eff,
+                        max_rounds=max_rounds, mode=eng.matmul_mode)
+                    best = np.asarray(best_d)
+            if best is None:
+                best_d, rounds = _dist_bidi(
+                    su, sv, req, frw, fm, jnp.asarray(ch.src),
+                    jnp.asarray(ch.dst), jnp.asarray(ch.lab),
+                    jnp.asarray(ch.evalid), it_cap, v_p=ch.v_p,
+                    n_states=n_states, max_m=m_eff, max_rounds=max_rounds)
                 best = np.asarray(best_d)
-        if best is None:
-            best_d, rounds = _dist_bidi(
-                su, sv, req, frw, fm, jnp.asarray(ch.src),
-                jnp.asarray(ch.dst), jnp.asarray(ch.lab),
-                jnp.asarray(ch.evalid), it_cap, v_p=ch.v_p,
-                n_states=n_states, max_m=m_eff, max_rounds=max_rounds)
-            best = np.asarray(best_d)
-        best_j[jobs[:real_n]] = best[:real_n]
-        stats._round_parts.append(rounds)
-        stats.corridor_active += ch.n_sub
-        stats.corridor_total += index.graph.n_vertices
-    bq = np.full(plan.n_queries, _DBIG, np.int64)
-    np.minimum.at(bq, plan.qid, best_j)
-    reach = bq < _DBIG
-    out[reach] = bq[reach]
-    if k is not None:
-        out[out > int(k)] = -1
-    stats.exact_jobs += plan.n_jobs
-    stats.phase2_s += time.perf_counter() - t0
+            best_j[jobs[:real_n]] = best[:real_n]
+            stats.add_chunk(rounds)
+            stats.corridor_active += ch.n_sub
+            stats.corridor_total += index.graph.n_vertices
+        bq = np.full(plan.n_queries, _DBIG, np.int64)
+        np.minimum.at(bq, plan.qid, best_j)
+        reach = bq < _DBIG
+        out[reach] = bq[reach]
+        if k is not None:
+            out[out > int(k)] = -1
+        stats.exact_jobs += plan.n_jobs
     return out
 
 
@@ -2210,14 +2239,14 @@ def rpq_batch(index: TDRIndex, queries: Sequence[tuple], *,
     if q_unroll is not None and q_unroll not in (4, 8, 16, 32):
         raise ValueError(f"q_unroll must be a power of two in 4..32, "
                          f"got {q_unroll!r}")
-    t0 = time.perf_counter()
     eng = index.engine(backend, engine_config)
     stats = stats if stats is not None else QueryStats()
     out = np.zeros(len(queries), dtype=bool)
     if not queries:
         return out
-    rows = [rpq_rows(index, r, max_m, stats=stats)
-            for (_, _, r) in queries]
+    with spans.span("query.plan", stats, "plan_s"):
+        rows = [rpq_rows(index, r, max_m, stats=stats)
+                for (_, _, r) in queries]
 
     low_ix = [i for i, rw in enumerate(rows) if rw.lowered is not None]
     if low_ix:
@@ -2263,104 +2292,102 @@ def rpq_batch(index: TDRIndex, queries: Sequence[tuple], *,
     # phase 2: automaton-product expansion.  The approx plan is single-
     # term per query (its job k is approxq position k), so it doubles as
     # the endpoint plan and the Bloom-corridor compaction source.
-    t1 = time.perf_counter()
-    ex = _executor(index, eng)
-    jobs_all = np.asarray([pos_of[i] for i in hard_ix], dtype=np.int64)
-    dev = PlanDevice(jnp.asarray(aplan.u), jnp.asarray(aplan.v),
-                     jnp.asarray(aplan.req_labels),
-                     jnp.asarray(aplan.forb_raw_w),
-                     jnp.asarray(aplan.full_mask))
-    done_all = np.zeros(len(jobs_all), dtype=bool)
-    for c0 in range(0, len(jobs_all), exact_chunk):
-        jobs = jobs_all[c0:c0 + exact_chunk]
-        real_n = len(jobs)
-        if real_n < exact_chunk:    # pad to a stable jit shape
-            jobs = np.concatenate(
-                [jobs, np.full(exact_chunk - real_n, jobs[0])])
-        ch = _kind_chunk(index, ex, aplan, dev, jobs, exact_mode)
-        qrows = [rows[hard_ix[c0 + (j if j < real_n else 0)]]
-                 for j in range(len(jobs))]
-        if q_unroll is None:
-            q_u = 4
-            while q_u < max(rw.nfa_states for rw in qrows):
-                q_u *= 2
-        else:
-            q_u = q_unroll
-        max_rounds = ch.v_p * q_u + 1    # product-graph diameter bound
-        tabs = jnp.asarray(np.stack([rw.tab for rw in qrows]))
-        rtabs = jnp.asarray(np.stack([rw.rtab for rw in qrows]))
-        accept = jnp.asarray(
-            np.asarray([rw.accept for rw in qrows], np.uint32))
-        su, sv = jnp.asarray(ch.su), jnp.asarray(ch.sv)
-        done = rounds = None
-        if eng.backend == "pallas" and ch.evalid.any():
-            # per-alphabet-label classes; the merged neutral class has a
-            # zero NFA table.  Skipped when the corridor held no real
-            # edges — the packed fake 0→0 edge would fabricate a letter.
-            special = set()
-            for rw in qrows:
-                special.update(rw.alpha)
-            if special_labels is not None:
-                special.update(int(l) for l in special_labels
-                               if 0 <= int(l) < index.graph.n_labels)
-            special = tuple(sorted(special))
-            kw_b = bitset.n_words(ch.v_p)
-            n_mats = 2 * (len(special) + 1)
-            if n_mats * ch.v_p * kw_b * 4 <= eng.config.max_dense_bytes:
-                class_label = jnp.asarray(
-                    np.asarray(special + (-1,), np.int32))
-                if ch.sub_ids is None:
-                    adj_rev = eng.label_class_adjacency(special,
-                                                        reverse=True)
-                    adj_fwd = eng.label_class_adjacency(special,
-                                                        reverse=False)
-                else:
-                    adj_rev = jnp.asarray(
-                        engine_mod.pack_label_class_edges_np(
-                            ch.src, ch.dst, ch.lab, ch.v_p, special,
-                            reverse=True))
-                    adj_fwd = jnp.asarray(
-                        engine_mod.pack_label_class_edges_np(
-                            ch.src, ch.dst, ch.lab, ch.v_p, special,
-                            reverse=False))
-                done_d, rounds = _rpq_bidi_matmul(
-                    su, sv, tabs, rtabs, accept, adj_rev, adj_fwd,
-                    class_label, max_rounds=max_rounds,
-                    mode=eng.matmul_mode, q_u=q_u)
+    with spans.span("query.phase2", stats, "phase2_s"):
+        ex = _executor(index, eng)
+        jobs_all = np.asarray([pos_of[i] for i in hard_ix], dtype=np.int64)
+        dev = PlanDevice(jnp.asarray(aplan.u), jnp.asarray(aplan.v),
+                         jnp.asarray(aplan.req_labels),
+                         jnp.asarray(aplan.forb_raw_w),
+                         jnp.asarray(aplan.full_mask))
+        done_all = np.zeros(len(jobs_all), dtype=bool)
+        for c0 in range(0, len(jobs_all), exact_chunk):
+            jobs = jobs_all[c0:c0 + exact_chunk]
+            real_n = len(jobs)
+            if real_n < exact_chunk:    # pad to a stable jit shape
+                jobs = np.concatenate(
+                    [jobs, np.full(exact_chunk - real_n, jobs[0])])
+            ch = _kind_chunk(index, ex, aplan, dev, jobs, exact_mode)
+            qrows = [rows[hard_ix[c0 + (j if j < real_n else 0)]]
+                     for j in range(len(jobs))]
+            if q_unroll is None:
+                q_u = 4
+                while q_u < max(rw.nfa_states for rw in qrows):
+                    q_u *= 2
+            else:
+                q_u = q_unroll
+            max_rounds = ch.v_p * q_u + 1    # product-graph diameter bound
+            tabs = jnp.asarray(np.stack([rw.tab for rw in qrows]))
+            rtabs = jnp.asarray(np.stack([rw.rtab for rw in qrows]))
+            accept = jnp.asarray(
+                np.asarray([rw.accept for rw in qrows], np.uint32))
+            su, sv = jnp.asarray(ch.su), jnp.asarray(ch.sv)
+            done = rounds = None
+            if eng.backend == "pallas" and ch.evalid.any():
+                # per-alphabet-label classes; the merged neutral class has a
+                # zero NFA table.  Skipped when the corridor held no real
+                # edges — the packed fake 0→0 edge would fabricate a letter.
+                special = set()
+                for rw in qrows:
+                    special.update(rw.alpha)
+                if special_labels is not None:
+                    special.update(int(l) for l in special_labels
+                                   if 0 <= int(l) < index.graph.n_labels)
+                special = tuple(sorted(special))
+                kw_b = bitset.n_words(ch.v_p)
+                n_mats = 2 * (len(special) + 1)
+                if n_mats * ch.v_p * kw_b * 4 <= eng.config.max_dense_bytes:
+                    class_label = jnp.asarray(
+                        np.asarray(special + (-1,), np.int32))
+                    if ch.sub_ids is None:
+                        adj_rev = eng.label_class_adjacency(special,
+                                                            reverse=True)
+                        adj_fwd = eng.label_class_adjacency(special,
+                                                            reverse=False)
+                    else:
+                        adj_rev = jnp.asarray(
+                            engine_mod.pack_label_class_edges_np(
+                                ch.src, ch.dst, ch.lab, ch.v_p, special,
+                                reverse=True))
+                        adj_fwd = jnp.asarray(
+                            engine_mod.pack_label_class_edges_np(
+                                ch.src, ch.dst, ch.lab, ch.v_p, special,
+                                reverse=False))
+                    done_d, rounds = _rpq_bidi_matmul(
+                        su, sv, tabs, rtabs, accept, adj_rev, adj_fwd,
+                        class_label, max_rounds=max_rounds,
+                        mode=eng.matmul_mode, q_u=q_u)
+                    done = np.asarray(done_d)
+            if done is None:
+                # padded-incidence gathers replace the scatter segment-OR
+                # (built from the real edges only, so padding rows need no
+                # mask on this path); degree skew past the cap falls back
+                e_real = int(ch.evalid.sum())
+                e_p = int(ch.src.shape[0])
+                ids_in = ids_out = None
+                if e_real:
+                    plan_in = graph_mod.incidence_plan(
+                        ch.dst[:e_real], ch.v_p, e_p)
+                    plan_out = graph_mod.incidence_plan(
+                        ch.src[:e_real], ch.v_p, e_p)
+                    gb = sum(a.size for a in plan_in + plan_out) * \
+                        len(jobs) * 4
+                    if gb <= ExactExecutor.GATHER_BYTES_CAP:
+                        ids_in = tuple(jnp.asarray(a) for a in plan_in)
+                        ids_out = tuple(jnp.asarray(a) for a in plan_out)
+                done_d, rounds = _rpq_bidi(
+                    su, sv, tabs, rtabs, accept, jnp.asarray(ch.src),
+                    jnp.asarray(ch.dst), jnp.asarray(ch.lab),
+                    jnp.asarray(ch.evalid), ids_in, ids_out, v_p=ch.v_p,
+                    max_rounds=max_rounds,
+                    chunk_words=eng.config.chunk_words, q_u=q_u)
                 done = np.asarray(done_d)
-        if done is None:
-            # padded-incidence gathers replace the scatter segment-OR
-            # (built from the real edges only, so padding rows need no
-            # mask on this path); degree skew past the cap falls back
-            e_real = int(ch.evalid.sum())
-            e_p = int(ch.src.shape[0])
-            ids_in = ids_out = None
-            if e_real:
-                plan_in = graph_mod.incidence_plan(
-                    ch.dst[:e_real], ch.v_p, e_p)
-                plan_out = graph_mod.incidence_plan(
-                    ch.src[:e_real], ch.v_p, e_p)
-                gb = sum(a.size for a in plan_in + plan_out) * \
-                    len(jobs) * 4
-                if gb <= ExactExecutor.GATHER_BYTES_CAP:
-                    ids_in = tuple(jnp.asarray(a) for a in plan_in)
-                    ids_out = tuple(jnp.asarray(a) for a in plan_out)
-            done_d, rounds = _rpq_bidi(
-                su, sv, tabs, rtabs, accept, jnp.asarray(ch.src),
-                jnp.asarray(ch.dst), jnp.asarray(ch.lab),
-                jnp.asarray(ch.evalid), ids_in, ids_out, v_p=ch.v_p,
-                max_rounds=max_rounds,
-                chunk_words=eng.config.chunk_words, q_u=q_u)
-            done = np.asarray(done_d)
-        done_all[c0:c0 + real_n] = done[:real_n]
-        stats._round_parts.append(rounds)
-        stats.corridor_active += ch.n_sub
-        stats.corridor_total += index.graph.n_vertices
-    for i, d in zip(hard_ix, done_all):
-        out[i] = bool(d)
-    stats.exact_jobs += len(jobs_all)
-    stats.phase2_s += time.perf_counter() - t1
-    stats.phase1_s += t1 - t0
+            done_all[c0:c0 + real_n] = done[:real_n]
+            stats.add_chunk(rounds)
+            stats.corridor_active += ch.n_sub
+            stats.corridor_total += index.graph.n_vertices
+        for i, d in zip(hard_ix, done_all):
+            out[i] = bool(d)
+        stats.exact_jobs += len(jobs_all)
     return out
 
 

@@ -96,6 +96,7 @@ from repro.core import rpq as rpq_mod
 from repro.core import snapshot as snapshot_mod
 from repro.core import tdr_build, tdr_query
 from repro.launch import compile_cache
+from repro.utils import spans
 
 LOG_NAME = "deltas.wal"
 _SNAP_RE = re.compile(r"snapshot-(\d+)\.tdr")
@@ -158,6 +159,9 @@ class ServeStats:
     served: int = 0              # requests answered via a batch
     batches: int = 0
     jobs: int = 0                # plan rows over all served batches
+    # Σ over requests answered via a batch of (start of their batch's
+    # ``serve.batch`` span) - submit time: the wait in the queue
+    queue_wait_s: float = 0.0
     cache_hits: int = 0          # resolved from the result cache
     dedup_hits: int = 0          # collapsed onto an in-batch duplicate
     rejected: int = 0            # non-blocking submits shed by admission
@@ -192,7 +196,7 @@ _MISS = object()
 
 class _Request:
     __slots__ = ("u", "v", "pattern", "rkey", "terms", "kind", "hops",
-                 "k", "with_lsn", "t_submit", "future")
+                 "k", "with_lsn", "t_submit", "rid", "future")
 
     def __init__(self, u, v, pattern, rkey, terms, kind="bool", hops=8,
                  k=None, with_lsn=False):
@@ -206,6 +210,7 @@ class _Request:
         self.k = k
         self.with_lsn = with_lsn
         self.t_submit = time.perf_counter()
+        self.rid = -1   # queue order, set at enqueue
         self.future: Future = Future()
 
 
@@ -267,6 +272,8 @@ class QueryServer:
         self.config = cfg
         self.stats = ServeStats()
         self._queue: collections.deque[_Request] = collections.deque()
+        self._next_rid = 0      # ids of enqueued requests (FIFO order)
+        self._batch_seq = 0     # batches taken by the scheduler thread
         self._lock = threading.Lock()
         self._not_empty = threading.Condition(self._lock)
         self._not_full = threading.Condition(self._lock)
@@ -444,6 +451,8 @@ class QueryServer:
                         f"queue at max_queue={cfg.max_queue} "
                         f"(timed out after {timeout}s)")
                 self._not_full.wait(rem)
+            req.rid = self._next_rid
+            self._next_rid += 1
             self._queue.append(req)
             self._not_empty.notify()
         return req.future
@@ -1035,8 +1044,14 @@ class QueryServer:
                 batch.event.set()
                 continue
             if batch:
+                self._batch_seq += 1
                 try:
-                    self._serve_batch(batch)
+                    # the queue is FIFO: a batch's request ids are one range
+                    with spans.span("serve.batch", batch=self._batch_seq,
+                                    rids=f"{batch[0].rid}-{batch[-1].rid}",
+                                    requests=len(batch),
+                                    jobs=sum(r.terms for r in batch)):
+                        self._serve_batch(batch)
                 except Exception as exc:  # noqa: BLE001 — the scheduler
                     # thread must never die silently: fail this batch's
                     # futures and keep serving
@@ -1054,43 +1069,48 @@ class QueryServer:
         batch ever straddles an index swap."""
         cfg = self.config
         with self._lock:
-            while not self._queue:
-                if not self._running:
-                    return None
-                self._not_empty.wait()
+            if not self._queue:
+                with spans.span("serve.wait_for_work"):
+                    while not self._queue:
+                        if not self._running:
+                            return None
+                        self._not_empty.wait()
             if not self._running and not self._drain:
                 return None
-            deadline = time.perf_counter() + cfg.max_wait_ms * 1e-3
-            batch: list[_Request] = []
-            jobs = 0
-            while True:
-                while self._queue:
-                    nxt = self._queue[0]
-                    if isinstance(nxt, _UpdateBarrier):
-                        if batch:   # serve what precedes the barrier first
+            with spans.span("serve.coalesce"):
+                deadline = time.perf_counter() + cfg.max_wait_ms * 1e-3
+                batch: list[_Request] = []
+                jobs = 0
+                while True:
+                    while self._queue:
+                        nxt = self._queue[0]
+                        if isinstance(nxt, _UpdateBarrier):
+                            # serve what precedes the barrier first
+                            if batch:
+                                self._not_full.notify_all()
+                                return batch
+                            self._queue.popleft()
+                            self._not_full.notify_all()
+                            return nxt
+                        if batch and jobs + nxt.terms > cfg.max_jobs:
                             self._not_full.notify_all()
                             return batch
                         self._queue.popleft()
-                        self._not_full.notify_all()
-                        return nxt
-                    if batch and jobs + nxt.terms > cfg.max_jobs:
-                        self._not_full.notify_all()
+                        batch.append(nxt)
+                        jobs += nxt.terms
+                        if jobs >= cfg.max_jobs:
+                            self._not_full.notify_all()
+                            return batch
+                    self._not_full.notify_all()
+                    rem = deadline - time.perf_counter()
+                    if rem <= 0 or not self._running:
                         return batch
-                    self._queue.popleft()
-                    batch.append(nxt)
-                    jobs += nxt.terms
-                    if jobs >= cfg.max_jobs:
-                        self._not_full.notify_all()
-                        return batch
-                self._not_full.notify_all()
-                rem = deadline - time.perf_counter()
-                if rem <= 0 or not self._running:
-                    return batch
-                self._not_empty.wait(rem)
+                    self._not_empty.wait(rem)
 
     def _serve_batch(self, batch: list[_Request]) -> None:
         """Answer one coalesced batch: dedup → plan-cache compile →
         per-kind executors → fan results out to futures + result cache."""
+        t_batch = time.perf_counter()   # the start of its serve.batch span
         cfg = self.config
         uniq: dict = {}   # rkey -> (u, v, pattern, kind, hops, k)
         fanout: dict = collections.defaultdict(list)
@@ -1128,24 +1148,28 @@ class QueryServer:
                 for req in fanout[k]:
                     _resolve(req.future, exc=exc)
             return
-        with self._lock:
-            self.stats.batches += 1
-            self.stats.served += sum(len(v) for v in fanout.values())
-            self.stats.jobs += jobs_total
-            if self._warmed_to and jobs_total and \
-                    graph_mod.pad_bucket(jobs_total, lo=cfg.min_bucket) \
-                    > self._warmed_to:
-                self.stats.overflow_batches += 1
-            if cfg.result_cache:
-                for k in keys:
-                    while len(self._results) >= cfg.result_cache:
-                        self._results.popitem(last=False)
-                    self._results[k] = answers[k]
-        for k in keys:
-            for req in fanout[k]:
-                _resolve(req.future,
-                         (answers[k], lsn) if req.with_lsn
-                         else answers[k])
+        with spans.span("serve.fanout"):
+            with self._lock:
+                self.stats.batches += 1
+                served = [req for v in fanout.values() for req in v]
+                self.stats.served += len(served)
+                self.stats.queue_wait_s += sum(
+                    t_batch - req.t_submit for req in served)
+                self.stats.jobs += jobs_total
+                if self._warmed_to and jobs_total and \
+                        graph_mod.pad_bucket(jobs_total, lo=cfg.min_bucket) \
+                        > self._warmed_to:
+                    self.stats.overflow_batches += 1
+                if cfg.result_cache:
+                    for k in keys:
+                        while len(self._results) >= cfg.result_cache:
+                            self._results.popitem(last=False)
+                        self._results[k] = answers[k]
+            for k in keys:
+                for req in fanout[k]:
+                    _resolve(req.future,
+                             (answers[k], lsn) if req.with_lsn
+                             else answers[k])
 
     def _answer_keys(self, keys: list, uniq: dict) -> dict:
         """Run every kind's executor over its slice of the unique keys.

@@ -177,6 +177,34 @@ def test_corridor_compaction_prunes_and_lazy_stats():
     assert stats.phase1_s > 0 and stats.phase2_s > 0
 
 
+def test_round_counters_count_chunks_and_stay_bounded():
+    """Every phase-2 chunk is counted with its rounds (at least one); the
+    unread device round counters are folded on read, and a stats object
+    that is never read holds fewer than ``_ROUND_PARTS_CAP`` of them."""
+    g = G.erdos_renyi(120, 1.2, 4, seed=5)
+    idx = tdr_build.build_index(g, CFG)
+    queries = _random_queries(np.random.default_rng(6), g, 40)
+    stats = tdr_query.QueryStats()
+    tdr_query.answer_batch(idx, queries, backend="segment", exact_chunk=8,
+                           exact_mode="full", stats=stats)
+    assert stats.exact_jobs > 8
+    assert stats.exact_chunks == -(-stats.exact_jobs // 8)
+    assert stats.exact_rounds >= stats.exact_chunks
+    assert stats._round_parts == []
+    assert stats.plan_s > 0
+
+    stats = tdr_query.QueryStats()
+    n = 3 * tdr_query._ROUND_PARTS_CAP + 5
+    for i in range(n):
+        stats.add_chunk(jnp.int32(i % 7))
+        assert len(stats._round_parts) < tdr_query._ROUND_PARTS_CAP
+    want = sum(i % 7 for i in range(n))
+    assert stats.exact_chunks == n
+    assert stats.exact_rounds == want
+    assert stats._round_parts == []
+    assert stats.exact_rounds == want
+
+
 def test_incidence_plan_matches_bruteforce():
     """One- and two-level padded incidence reduce to the same segment OR
     (two-level triggers on the pa graph's hub tail)."""

@@ -154,6 +154,24 @@ def test_threaded_submit_matches_direct(served_graph):
         assert all(v == want[i] for v in vals), (i, vals, want[i])
 
 
+def test_queue_wait_and_request_ids(served_graph):
+    """Enqueued requests take FIFO ids; each served request's wait from
+    submit to the start of its batch is summed in ``queue_wait_s``."""
+    g, idx = served_graph
+    pool = _query_pool(g, 13, n=12)
+    want = tdr_query.answer_batch(idx, pool).tolist()
+    server = serve.QueryServer(idx, result_cache=0)
+    futs = [server.submit(u, v, p) for u, v, p in pool]   # queued first
+    assert [r.rid for r in server._queue] == list(range(len(pool)))
+    server.start()
+    try:
+        assert [f.result(timeout=60) for f in futs] == want
+    finally:
+        server.stop()
+    assert server.stats.served == len(pool)
+    assert server.stats.queue_wait_s > 0
+
+
 def test_admission_control(served_graph):
     g, idx = served_graph
     q = _query_pool(g, 5)[0]

@@ -44,7 +44,8 @@ LEG_A = dict(name="A", graph="pa", n_vertices=8192, n_labels=8,
              mix={"bool": 64, "dist": 16, "witness": 8, "count": 8,
                   "rpq": 16},
              update=(16, 4), after_update=32,
-             kernels=("bitset_matmul", "block_sparse_matmul", "way_filter"))
+             kernels=("bitset_matmul", "block_sparse_matmul",
+                      "lane_matmul_edges", "way_filter"))
 LEG_B = dict(name="B", graph="er", n_vertices=1 << 18, n_labels=16,
              backend="segment",
              mix={"bool": 32, "dist": 8, "rpq": 8, "witness": 4,
@@ -278,8 +279,8 @@ def run_leg(spec: dict, seed: int, meter: CompileMeter | None = None,
     report["peak_bytes_in_use"] = peak_bytes()
     report["kernel_invocations"] = {
         k: ops.KERNEL_INVOCATIONS[k] - kinv0.get(k, 0)
-        for k in ("bitset_matmul", "lane_matmul", "block_sparse_matmul",
-                  "way_filter")}
+        for k in ("bitset_matmul", "lane_matmul", "lane_matmul_edges",
+                  "block_sparse_matmul", "way_filter")}
     report["smoke_timing_s"] = {"graph_and_queries": gen_s,
                                 "build": build_s, "warmup": warmup_s,
                                 "queries_and_oracle": query_s,

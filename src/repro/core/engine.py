@@ -28,6 +28,8 @@ boolean plane at rest) behind a pluggable backend:
   (``[V, ceil(V/32)]`` uint32, bit j of row i == edge i→j).  Real kernel on
   TPU, interpret mode elsewhere.  Dense ``V×V/8`` bytes, so the engine
   auto-falls back to ``segment`` above ``EngineConfig.max_dense_bytes``.
+  The boolean phase-2 class expansion instead walks per-class edge lists
+  (``class_edge_lists_np``, ``_edge_rows``: ``lane_matmul_edges``).
 
 Backend selection contract (see ARCHITECTURE.md):
 
@@ -136,6 +138,39 @@ def pack_label_class_edges_np(src: np.ndarray, dst: np.ndarray,
     return out
 
 
+def class_edge_lists_np(src: np.ndarray, dst: np.ndarray,
+                        labels: np.ndarray, special_labels, *,
+                        reverse: bool = True
+                        ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Per-label-class edge lists ``(rows, cols, count)`` from raw edge
+    arrays: the operand of ``kernels.ops.frontier_step_edges``.
+
+    The same classes as ``pack_label_class_edges_np`` (one per special
+    label, then the neutral class), and the same ``reverse`` meaning:
+    class ``c``'s first ``count[c]`` entries are the set bits ``(row,
+    col)`` of its packed matrix, so one round ORs ``x[col]`` into
+    ``out[row]``.  ``rows`` and ``cols`` are int32 ``[C+1, E_pad]``,
+    sorted by row within a class; ``E_pad`` is the largest class's count
+    on the ``graph.pad_bucket`` grid, so an update that moves edge counts
+    keeps the shape within a bucket.  Padding entries are 0 and never
+    read."""
+    special = list(special_labels)
+    rows, cols = (dst, src) if reverse else (src, dst)
+    cls = np.full(labels.shape[0], len(special), dtype=np.int64)
+    for i, l in enumerate(special):
+        cls[labels == l] = i
+    count = np.bincount(cls, minlength=len(special) + 1)
+    e_pad = pad_bucket(max(int(count.max()), 1), lo=32)
+    order = np.lexsort((rows, cls))
+    slot = np.arange(order.shape[0]) - np.repeat(np.cumsum(count) - count,
+                                                 count)
+    out_r = np.zeros((len(special) + 1, e_pad), dtype=np.int32)
+    out_c = np.zeros_like(out_r)
+    out_r[cls[order], slot] = rows[order]
+    out_c[cls[order], slot] = cols[order]
+    return out_r, out_c, count.astype(np.int32)
+
+
 def pack_label_class_adjacency_np(graph: Graph, special_labels,
                                   *, reverse: bool = True) -> np.ndarray:
     """Whole-graph wrapper over ``pack_label_class_edges_np``."""
@@ -173,6 +208,17 @@ def _closure_segment(base: jax.Array, gather_idx: jax.Array,
     r, _, rounds = jax.lax.while_loop(cond, body,
                                       (base, jnp.bool_(True), jnp.int32(0)))
     return r, rounds
+
+
+def _edge_rows(edges: tuple[jax.Array, jax.Array, jax.Array],
+               x: jax.Array, mode: str) -> jax.Array:
+    """Boolean sibling of ``_matmul_rows`` over one class's edge list
+    ``(rows, cols, count)`` (``class_edge_lists_np``): ``out[r] |= x[c]``,
+    bit-identical to ``_matmul_rows`` on the class's packed matrix."""
+    from repro.kernels import ops  # deferred: kernels import repro.core
+    rows, cols, count = edges
+    return ops.frontier_step_edges(rows, cols, count, x,
+                                   n_rows=x.shape[0], mode=mode)
 
 
 def _matmul_rows(adj: jax.Array, x: jax.Array, mode: str,
@@ -453,6 +499,7 @@ class Engine:
         self._adj: dict[bool, jax.Array] = {}
         self._bcomp: dict[bool, BlockCompressed] = {}
         self._label_adj: dict[tuple, jax.Array] = {}
+        self._label_edges: dict[tuple, tuple] = {}
         self._rev_graph: Graph | None = None
 
     # ------------------------------------------------------------ operands
@@ -506,16 +553,33 @@ class Engine:
         frontier expansion; ``reverse=False`` drives the backward frontier
         of the bidirectional executor."""
         labels = tuple(sorted(set(int(l) for l in special_labels)))
-        key = (labels, reverse)
-        if key in self._label_adj:
-            self._label_adj[key] = self._label_adj.pop(key)  # refresh LRU
+        return self._lru(self._label_adj, (labels, reverse),
+                         lambda: jnp.asarray(pack_label_class_adjacency_np(
+                             self.graph, labels, reverse=reverse)))
+
+    def label_class_edges(self, special_labels, *, reverse: bool = True
+                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+        """Per-label-class edge lists ``(rows, cols, count)`` on device
+        (``class_edge_lists_np`` over the whole graph; LRU-cached like
+        ``label_class_adjacency``, whose ``reverse`` meaning it keeps)."""
+        labels = tuple(sorted(set(int(l) for l in special_labels)))
+        g = self.graph
+        return self._lru(self._label_edges, (labels, reverse),
+                         lambda: tuple(jnp.asarray(a) for a in
+                                       class_edge_lists_np(
+                                           g.src, g.indices, g.labels,
+                                           labels, reverse=reverse)))
+
+    def _lru(self, cache: dict, key, build):
+        """``cache[key]``, built on a miss; at most ``LABEL_ADJ_CACHE``
+        entries, the least recently used dropped first."""
+        if key in cache:
+            cache[key] = cache.pop(key)  # refresh LRU
         else:
-            while len(self._label_adj) >= self.LABEL_ADJ_CACHE:
-                self._label_adj.pop(next(iter(self._label_adj)))
-            self._label_adj[key] = jnp.asarray(
-                pack_label_class_adjacency_np(self.graph, labels,
-                                              reverse=reverse))
-        return self._label_adj[key]
+            while len(cache) >= self.LABEL_ADJ_CACHE:
+                cache.pop(next(iter(cache)))
+            cache[key] = build()
+        return cache[key]
 
     # ---------------------------------------------------------- primitives
     def segment_or(self, values: jax.Array, segment_ids: jax.Array,
@@ -678,8 +742,8 @@ class Engine:
         only the rows whose edge set changed (sources for the forward
         matrix, destinations for the reverse one) are re-derived from the
         new CSR and scattered in on device — O(|touched rows|) transfer
-        instead of O(V·V/8).  Label-class adjacency caches are dropped
-        (they rebuild lazily on the next query batch)."""
+        instead of O(V·V/8).  Label-class adjacency and edge-list caches
+        are dropped (they rebuild lazily on the next query batch)."""
         if graph.n_vertices != self.graph.n_vertices:
             raise ValueError("apply_delta requires a fixed vertex set")
         new = object.__new__(Engine)
@@ -692,6 +756,7 @@ class Engine:
         new._adj = {}
         new._bcomp = {}
         new._label_adj = {}
+        new._label_edges = {}
         new._rev_graph = None
         rev_csr = None
 

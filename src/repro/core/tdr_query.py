@@ -179,6 +179,7 @@ class QueryStats:
     phase1_s: float = 0.0
     phase2_s: float = 0.0
     exact_chunks: int = 0      # phase-2 chunks run (each adds its rounds)
+    edge_chunks: int = 0       # ... of them expanded by lane_matmul_edges
     # rounds of the chunks already read, plus the device round counters
     # not yet read: those are fetched on .exact_rounds access (or once
     # _ROUND_PARTS_CAP pile up), so dispatching never waits on them
@@ -656,13 +657,14 @@ def _expand_bidi_full(jobs, dev, n_out, n_in, vtx_packed, sub_lab,
         chunk_words)
 
 
-def _bidi_matmul_core(su, sv, adj_rev, adj_fwd, class_label, req_labels,
-                      forb_raw_w, full_mask, cor_w, n_states: int,
-                      max_m: int, max_rounds: int, mode: str):
-    """Pallas-backend bidirectional fixpoint: one ``bitset_matmul`` per
-    label class per direction per round, on packed (sub-)adjacency
-    bit-matrices (forward frontier uses the reverse matrices, backward the
-    forward ones)."""
+def _bidi_matmul_core(su, sv, edges_rev, edges_fwd, class_label,
+                      req_labels, forb_raw_w, full_mask, cor_w,
+                      n_states: int, max_m: int, max_rounds: int, mode: str):
+    """Pallas-backend bidirectional fixpoint: one ``lane_matmul_edges``
+    call per label class per direction per round, on the classes' edge
+    lists ``(rows, cols, count)`` of ``engine.class_edge_lists_np``
+    (forward frontier uses the reverse lists, backward the forward
+    ones), so a round's work grows with the edges, not with V²."""
     q_n = su.shape[0]
     v_p = cor_w.shape[0]
     neutral = class_label < 0
@@ -673,42 +675,42 @@ def _bidi_matmul_core(su, sv, adj_rev, adj_fwd, class_label, req_labels,
     f0 = jnp.zeros((v_p, q_n), jnp.uint32).at[su, iota].set(jnp.uint32(1))
     b0 = jnp.zeros((v_p, q_n), jnp.uint32).at[sv, iota].set(jnp.uint32(1))
 
-    def push(frontier, adj_set):
+    def push(frontier, edge_set):
         # scan (not unroll) over label classes: one kernel call *site* per
         # direction keeps the while-loop body's XLA program small — an
         # unrolled 2·(C+1) pallas calls per round made compiles explode
         def body(upd, operand):
-            adj_c, allow_c, has_c, sh_c = operand
-            y = engine_mod._matmul_rows(adj_c, frontier, mode)[:v_p]
+            edges_c, allow_c, has_c, sh_c = operand
+            y = engine_mod._edge_rows(edges_c, frontier, mode)
             return upd | _transition(y & allow_c[None, :],
                                      has_c[None, :], sh_c[None, :]), None
         upd, _ = jax.lax.scan(body, jnp.zeros_like(frontier),
-                              (adj_set, allow, has, sh))
+                              (edge_set, allow, has, sh))
         return upd
 
     return _bidi_loop(
         f0, b0,
-        lambda f: push(f, adj_rev),
-        lambda b: push(b, adj_fwd),
+        lambda f: push(f, edges_rev),
+        lambda b: push(b, edges_fwd),
         cor_w, sup_need, max_rounds)
 
 
 @functools.partial(jax.jit, static_argnames=("n_states", "max_m",
                                              "max_rounds", "mode"))
-def _expand_bidi_matmul(jobs, dev, su, sv, adj_rev, adj_fwd, class_label,
-                        cor, *, n_states: int, max_m: int, max_rounds: int,
-                        mode: str):
+def _expand_bidi_matmul(jobs, dev, su, sv, edges_rev, edges_fwd,
+                        class_label, cor, *, n_states: int, max_m: int,
+                        max_rounds: int, mode: str):
     """Compacted-subgraph entry (``cor`` = membership bool [V', Q])."""
     req_labels, forb_raw_w, full_mask = _job_rows(jobs, dev, max_m)
     return _bidi_matmul_core(
-        su, sv, adj_rev, adj_fwd, class_label, req_labels, forb_raw_w,
+        su, sv, edges_rev, edges_fwd, class_label, req_labels, forb_raw_w,
         full_mask, bitset.full_words_where(cor), n_states, max_m,
         max_rounds, mode)
 
 
 @functools.partial(jax.jit, static_argnames=("n_states", "max_m",
                                              "max_rounds", "mode"))
-def _expand_bidi_matmul_full(jobs, dev, adj_rev, adj_fwd, class_label,
+def _expand_bidi_matmul_full(jobs, dev, edges_rev, edges_fwd, class_label,
                              n_out, n_in, vtx_packed, *, n_states: int,
                              max_m: int, max_rounds: int, mode: str):
     """Full-graph entry: corridor mask built on device from the Blooms."""
@@ -716,7 +718,7 @@ def _expand_bidi_matmul_full(jobs, dev, adj_rev, adj_fwd, class_label,
     u, v = dev.u[jobs], dev.v[jobs]
     cor_w = _corridor_mask(u, v, n_out[u], n_in[v], vtx_packed)
     return _bidi_matmul_core(
-        su=u, sv=v, adj_rev=adj_rev, adj_fwd=adj_fwd,
+        su=u, sv=v, edges_rev=edges_rev, edges_fwd=edges_fwd,
         class_label=class_label, req_labels=req_labels,
         forb_raw_w=forb_raw_w, full_mask=full_mask, cor_w=cor_w,
         n_states=n_states, max_m=max_m, max_rounds=max_rounds, mode=mode)
@@ -813,6 +815,7 @@ class ChunkResult:
     rounds: Any             # device int32 scalar (or int)
     n_active: int = 0       # |V'| this chunk ran on
     v_total: int = 0        # |V| of the full graph
+    edge_kernel: bool = False  # expanded by the edge-list class kernel
 
 
 class ExactExecutor:
@@ -990,24 +993,26 @@ class ExactExecutor:
         if use_matmul:
             class_label = jnp.asarray(np.asarray(special + (-1,), np.int32))
             if compacted:
-                adj_rev = jnp.asarray(engine_mod.pack_label_class_edges_np(
-                    s, d, l, v_p, special, reverse=True))
-                adj_fwd = jnp.asarray(engine_mod.pack_label_class_edges_np(
-                    s, d, l, v_p, special, reverse=False))
+                edges_rev, edges_fwd = (
+                    tuple(jnp.asarray(a) for a in
+                          engine_mod.class_edge_lists_np(
+                              s, d, l, special, reverse=rev))
+                    for rev in (True, False))
                 reached, rounds = _expand_bidi_matmul(
                     jobs_j, dev, jnp.asarray(su), jnp.asarray(sv),
-                    adj_rev, adj_fwd, class_label, jnp.asarray(cor),
+                    edges_rev, edges_fwd, class_label, jnp.asarray(cor),
                     n_states=n_states, max_m=m_eff, max_rounds=max_rounds,
                     mode=eng.matmul_mode)
             else:
-                adj_rev = eng.label_class_adjacency(special, reverse=True)
-                adj_fwd = eng.label_class_adjacency(special, reverse=False)
+                edges_rev = eng.label_class_edges(special, reverse=True)
+                edges_fwd = eng.label_class_edges(special, reverse=False)
                 reached, rounds = _expand_bidi_matmul_full(
-                    jobs_j, dev, adj_rev, adj_fwd, class_label, idx.n_out,
-                    idx.n_in, idx.vtx_packed, n_states=n_states,
+                    jobs_j, dev, edges_rev, edges_fwd, class_label,
+                    idx.n_out, idx.n_in, idx.vtx_packed, n_states=n_states,
                     max_m=m_eff, max_rounds=max_rounds,
                     mode=eng.matmul_mode)
-            return ChunkResult(jobs, q_n, reached, rounds, n_sub, v_n)
+            return ChunkResult(jobs, q_n, reached, rounds, n_sub, v_n,
+                               edge_kernel=True)
 
         if compacted:
             e_real = s.shape[0]
@@ -1355,6 +1360,7 @@ def answer_plan(index: TDRIndex, plan: QueryPlan,
                 hit = res.jobs[:res.real_n][reached]
                 np.logical_or.at(answers, plan_p.qid[hit], True)
                 stats.add_chunk(res.rounds)
+                stats.edge_chunks += res.edge_kernel
                 stats.corridor_active += res.n_active
                 stats.corridor_total += res.v_total
     return answers

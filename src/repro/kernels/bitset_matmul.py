@@ -17,9 +17,16 @@ accumulator — the arithmetic shape of a matmul without an MXU contraction
 MXU variant that unpacks to bf16 and thresholds a real matmul — see
 ARCHITECTURE.md ("Kernel lowerings") for the roofline comparison.
 
-Both the index-build closure fixpoint and the query-side product-graph
-expansion dispatch here when ``repro.core.engine`` selects the ``pallas``
-backend (interpret mode off-TPU); see ARCHITECTURE.md for the layering.
+The index-build closure fixpoint, the ``min``/``sum`` lane carriers and
+the RPQ and legacy query executors dispatch here when
+``repro.core.engine`` selects the ``pallas`` backend (interpret mode
+off-TPU); see ARCHITECTURE.md for the layering.  The boolean phase-2
+class expansion of the PCR executor does not: its operand is each label
+class's *edge list*, and ``lane_matmul_edges`` (end of this module)
+expands it in work that grows with the class's edges, where the dense
+kernel gates all V² adjacency bits whatever the density.  The dense cap
+(``EngineConfig.max_dense_bytes``) still decides which chunks take the
+Pallas path at all.
 
 Tiling: grid (M/TI, W/TW, Kw/TKW); the word axis is innermost
 ("arbitrary") so the output tile stays resident in VMEM while adjacency and
@@ -198,3 +205,112 @@ def bitset_matmul(a_packed: jax.Array, x: jax.Array, *, ti: int = 128,
     """
     return lane_matmul(a_packed, x, op="or", ti=ti, tk=tk, tw=tw,
                        interpret=interpret)
+
+
+# ------------------------------------------------- edge-list class expansion
+#: edges per grid step of ``lane_matmul_edges``: two int32 SMEM blocks of
+#: 32 KiB each (double-buffered: 128 KiB of the 1 MiB SMEM)
+EDGE_CHUNK = 8192
+#: edges per rolled loop iteration (the scalar loads of one step overlap
+#: the vector work of the others)
+EDGE_UNROLL = 8
+
+
+def _edges_kernel(cnt_ref, dst_ref, src_ref, x_ref, o_ref, *, chunk: int):
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.int32)
+
+    # the real edges of this grid step; padding entries are never read
+    n = jnp.clip(cnt_ref[0] - i * chunk, 0, chunk)
+
+    def edge(e):
+        d = dst_ref[e]
+        row = x_ref[pl.ds(src_ref[e], 1), :]
+        o_ref[pl.ds(d, 1), :] = o_ref[pl.ds(d, 1), :] | row
+
+    def block(b, carry):
+        for u in range(EDGE_UNROLL):
+            edge(b * EDGE_UNROLL + u)
+        return carry
+
+    def tail(e, carry):
+        edge(e)
+        return carry
+
+    jax.lax.fori_loop(0, n // EDGE_UNROLL, block, 0)
+    jax.lax.fori_loop(n - n % EDGE_UNROLL, n, tail, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("n_rows", "chunk",
+                                             "interpret"))
+def lane_matmul_edges(dst: jax.Array, src: jax.Array, count: jax.Array,
+                      x: jax.Array, *, n_rows: int, chunk: int = EDGE_CHUNK,
+                      interpret: bool = False) -> jax.Array:
+    """``out[dst[e], :] |= x[src[e], :]`` over the first ``count`` edges.
+
+    The boolean class expansion of phase 2 from the class's edge list:
+    bit-identical to ``lane_matmul(a, x, op="or")`` on the packed matrix
+    ``a`` holding the same edges (bit ``src`` of row ``dst``), duplicates
+    and self-loops included, in work that grows with ``count`` and not
+    with ``n_rows · x.shape[0]``.
+
+    Args:
+      dst, src: int32 [E_pad] edge endpoints, ``dst`` < ``n_rows`` and
+                ``src`` < ``x.shape[0]`` for the first ``count`` entries;
+                the rest is padding, never read.  Sorting by ``dst``
+                keeps one output row hot across its run of edges.
+      count:    int32 scalar, the number of real edges.
+      x:        uint32 [K, W] carrier (packed state words).
+      n_rows:   rows of the output.
+      chunk:    edges per grid step; the default fits SMEM, smaller
+                values are for interpret-mode tests of the grid.
+    Returns:
+      uint32 [n_rows, W].
+
+    Grid: one step per ``chunk`` edges (``E_pad`` is padded up to a
+    multiple of it), the edge blocks in SMEM, the real count
+    scalar-prefetched.  The carrier and the output are whole-array VMEM
+    blocks resident across the grid, one buffer each: VMEM holds
+    ``2 · R · 128⌈W/128⌉ · 4`` bytes (R = rows rounded up to 8), 8 MiB at
+    V=8192 and W=32 (4 MiB each, lane-padded).  A step walks its real
+    edges with a one-row dynamic sublane load of ``x``, an OR and a
+    one-row store into the output row.
+    """
+    e_pad = dst.shape[0]
+    chunk = max(1, min(chunk, e_pad))
+    e_full = -(-e_pad // chunk) * chunk
+    if e_full > e_pad:
+        dst = jnp.pad(dst, (0, e_full - e_pad))
+        src = jnp.pad(src, (0, e_full - e_pad))
+    k, w = x.shape
+    r_pad = -(-max(n_rows, k) // 8) * 8
+    x_i = jnp.pad(jax.lax.bitcast_convert_type(x.astype(jnp.uint32),
+                                               jnp.int32),
+                  ((0, r_pad - k), (0, 0)))
+    vmem = 2 * r_pad * (-(-w // LANES) * LANES) * 4
+    resident = dict(pipeline_mode=pl.Buffered(1))
+    out = pl.pallas_call(
+        functools.partial(_edges_kernel, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                 # the real edge count
+            grid=(e_full // chunk,),
+            in_specs=[
+                pl.BlockSpec((chunk,), lambda i, c: (i,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((chunk,), lambda i, c: (i,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((r_pad, w), lambda i, c: (0, 0), **resident),
+            ],
+            out_specs=pl.BlockSpec((r_pad, w), lambda i, c: (0, 0),
+                                   **resident)),
+        out_shape=jax.ShapeDtypeStruct((r_pad, w), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
+        interpret=interpret,
+    )(jnp.reshape(count, (1,)).astype(jnp.int32), dst.astype(jnp.int32),
+      src.astype(jnp.int32), x_i)
+    return jax.lax.bitcast_convert_type(out[:n_rows], jnp.uint32)

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.core import bitset
 from . import block_sparse, ref
-from .bitset_matmul import bitset_matmul, lane_matmul
+from .bitset_matmul import bitset_matmul, lane_matmul, lane_matmul_edges
 from .pattern_filter import way_filter
 from .popcount import popcount_rows
 
@@ -85,6 +85,24 @@ def frontier_step_lanes(a_packed: jax.Array, x: jax.Array, *, op: str,
                            interpret=(mode == "interpret"), **tile_kw)
     if mode == "ref":
         return ref.lane_matmul_ref(a_packed, x, op=op, cap=cap)
+    raise ValueError(mode)
+
+
+def frontier_step_edges(dst: jax.Array, src: jax.Array, count: jax.Array,
+                        x: jax.Array, *, n_rows: int,
+                        mode: str = "auto") -> jax.Array:
+    """One boolean expansion round from an edge list:
+    ``out[dst[e]] |= x[src[e]]`` over the first ``count`` edges (see
+    ``bitset_matmul.lane_matmul_edges``).  Same mode contract as
+    ``frontier_step`` less "mxu"; "ref" is a packed segment-OR."""
+    if mode == "auto":
+        mode = "pallas" if _on_tpu() else "ref"
+    if mode in ("pallas", "interpret"):
+        KERNEL_INVOCATIONS["lane_matmul_edges"] += 1
+        return lane_matmul_edges(dst, src, count, x, n_rows=n_rows,
+                                 interpret=(mode == "interpret"))
+    if mode == "ref":
+        return ref.lane_matmul_edges_ref(dst, src, count, x, n_rows=n_rows)
     raise ValueError(mode)
 
 

@@ -48,6 +48,19 @@ def lane_matmul_ref(a_packed: jax.Array, x: jax.Array, *, op: str,
                        jnp.uint32(cap)).astype(x.dtype)
 
 
+def lane_matmul_edges_ref(dst: jax.Array, src: jax.Array,
+                          count: jax.Array, x: jax.Array, *,
+                          n_rows: int) -> jax.Array:
+    """``out[dst[e], :] |= x[src[e], :]`` over the first ``count`` edges —
+    the oracle of ``bitset_matmul.lane_matmul_edges``: a packed
+    segment-OR of the gathered rows, padding entries sent to a dropped
+    segment."""
+    live = jnp.arange(dst.shape[0]) < count
+    seg = jnp.where(live, dst, n_rows)
+    return bitset.segment_or_words(x[jnp.where(live, src, 0)], seg,
+                                   num_segments=n_rows)
+
+
 def way_filter_ref(h_vtx, h_lab, v_vtx, v_lab, vbits, req, forb, null_plane):
     """Reference way-viability predicate (mirrors tdr_query phase 1)."""
     has_tgt = bitset.words_contain(h_vtx, vbits[:, None, :])

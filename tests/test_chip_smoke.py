@@ -32,6 +32,7 @@ def test_leg_matches_oracle_at_v256(spec):
     if spec["backend"] == "pallas":
         assert rep["interpret"]          # CPU: kernels run interpreted
         assert rep["kernel_invocations"]["bitset_matmul"] > 0
+        assert rep["kernel_invocations"]["lane_matmul_edges"] > 0
         assert rep["kernel_invocations"]["way_filter"] > 0
 
 

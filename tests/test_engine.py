@@ -142,6 +142,50 @@ def test_exact_modes_bit_equal_pallas():
             assert got == want, (seed, mode)
 
 
+@pytest.mark.parametrize("mode", ["full", "compact"])
+@pytest.mark.parametrize("seed", [3, 11])
+def test_pallas_edge_kernel_answers_match_oracle(mode, seed):
+    """Phase 2 on the pallas backend (interpret mode) expands every chunk
+    with the edge-list class kernel, full-graph and corridor-compacted,
+    and answers as ``dfs_baseline`` does."""
+    rng = np.random.default_rng(seed)
+    g = G.random_graph("er", 96, 1.3, 4, seed=seed)
+    idx = tdr_build.build_index(g, CFG)
+    queries = _random_queries(rng, g, 16)
+    want = [dfs_baseline.answer_pcr(g, u, v, p) for u, v, p in queries]
+    stats = tdr_query.QueryStats()
+    got = tdr_query.answer_batch(idx, queries, backend="pallas",
+                                 exact_mode=mode, exact_chunk=8,
+                                 stats=stats).tolist()
+    assert got == want
+    assert stats.exact_chunks > 0
+    assert stats.edge_chunks == stats.exact_chunks
+    if mode == "compact":
+        assert stats.corridor_occupancy < 1.0
+
+
+@pytest.mark.parametrize("reverse", [True, False])
+@pytest.mark.parametrize("special", [(), (1,), (0, 2, 3)])
+def test_class_edge_lists_match_packed_classes(special, reverse):
+    """Each class's edge list sets exactly the bits of its packed
+    matrix (same classes, same ``reverse`` meaning), sorted by row, with
+    ``E_pad`` on the ``pad_bucket`` grid."""
+    g = G.random_graph("pa", 70, 3.0, 4, seed=4)
+    packed = engine.pack_label_class_adjacency_np(g, special,
+                                                  reverse=reverse)
+    rows, cols, count = engine.class_edge_lists_np(
+        g.src, g.indices, g.labels, special, reverse=reverse)
+    assert rows.shape == cols.shape == (len(special) + 1, rows.shape[1])
+    assert rows.shape[1] == G.pad_bucket(int(count.max()), lo=32)
+    assert count.sum() == g.n_edges
+    for c in range(len(special) + 1):
+        n = count[c]
+        assert np.all(np.diff(rows[c, :n]) >= 0)
+        a = np.zeros_like(packed[c])
+        bitset.set_bits_np(a, (rows[c, :n],), cols[c, :n])
+        np.testing.assert_array_equal(a, packed[c])
+
+
 def test_self_cycle_queries_exact():
     """u==v with required labels is satisfiable only by a cycle through
     u collecting them — exact on every executor path."""
@@ -283,15 +327,18 @@ def test_pallas_backend_invokes_bitset_matmul():
     after_build = ops.KERNEL_INVOCATIONS["bitset_matmul"]
     assert after_build > before, "build fixpoint skipped the Pallas kernel"
 
-    # a query mix that cannot all be resolved by phase 1 filters
+    # a query mix that cannot all be resolved by phase 1 filters; phase 2
+    # expands its label classes with the edge-list kernel
     rng = np.random.default_rng(0)
     queries = _random_queries(rng, g, 30)
     stats = tdr_query.QueryStats()
+    edges_before = ops.KERNEL_INVOCATIONS["lane_matmul_edges"]
     tdr_query.answer_batch(idx, queries, backend="pallas", stats=stats)
-    after_query = ops.KERNEL_INVOCATIONS["bitset_matmul"]
+    after_query = ops.KERNEL_INVOCATIONS["lane_matmul_edges"]
     assert stats.exact_jobs > 0, "no job reached phase 2; pick other seeds"
-    assert after_query > after_build, \
+    assert after_query > edges_before, \
         "exact expansion skipped the Pallas kernel"
+    assert stats.edge_chunks == stats.exact_chunks > 0
 
 
 def test_segment_backend_uses_no_pallas_kernel():
@@ -299,10 +346,12 @@ def test_segment_backend_uses_no_pallas_kernel():
     g = G.erdos_renyi(40, 2.0, 4, seed=1)
     before = dict(ops.KERNEL_INVOCATIONS)
     idx = tdr_build.build_index(g, CFG, backend="segment")
+    stats = tdr_query.QueryStats()
     tdr_query.answer_batch(
         idx, _random_queries(np.random.default_rng(1), g, 10),
-        backend="segment")
+        backend="segment", stats=stats)
     assert dict(ops.KERNEL_INVOCATIONS) == before
+    assert stats.exact_chunks > 0 and stats.edge_chunks == 0
 
 
 # ------------------------------------------------------ backend selection
@@ -336,7 +385,12 @@ def test_label_adjacency_cache_is_bounded():
     eng = engine.make_engine(g, backend="pallas")
     for l in range(8):
         eng.label_class_adjacency((l,))
+        eng.label_class_edges((l,))
     assert len(eng._label_adj) <= engine.Engine.LABEL_ADJ_CACHE
+    assert len(eng._label_edges) <= engine.Engine.LABEL_ADJ_CACHE
+    # a hit returns the cached operand and refreshes it as most recent
+    assert eng.label_class_edges((7,)) is eng.label_class_edges((7,))
+    assert next(reversed(eng._label_edges)) == ((7,), True)
 
 
 def test_executor_falls_back_when_class_set_blows_cap():

@@ -112,3 +112,65 @@ def test_frontier_step_is_one_bfs_round():
     out_bool = np.unpackbits(
         out.view(np.uint8), axis=1, bitorder="little")[:, :64].astype(bool)
     np.testing.assert_array_equal(out_bool, adj)
+
+
+# --------------------------------------------- edge-list class expansion
+def _edge_case(v, q, edges, e_pad, seed):
+    """A class's edge list sorted by destination row, padded to ``e_pad``
+    with in-range garbage (a pad entry that were read would set bits)."""
+    rng = np.random.default_rng(seed)
+    dst = np.array([d for d, _ in edges], np.int32)
+    src = np.array([s for _, s in edges], np.int32)
+    order = np.argsort(dst, kind="stable")
+    dst_p = rng.integers(0, v, e_pad).astype(np.int32)
+    src_p = rng.integers(0, v, e_pad).astype(np.int32)
+    dst_p[:len(edges)] = dst[order]
+    src_p[:len(edges)] = src[order]
+    x = rng.integers(1, 2 ** 32, size=(v, q), dtype=np.uint32)
+    return dst_p, src_p, len(edges), x
+
+
+def _random_edges(v, n, seed):
+    rng = np.random.default_rng(seed)
+    return [(int(d), int(s)) for d, s in rng.integers(0, v, (n, 2))]
+
+
+@pytest.mark.parametrize("v,q,edges,e_pad,chunk", [
+    (37, 7, [], 32, None),                               # empty class
+    (24, 7, [(3, 5), (3, 5), (4, 4), (0, 0), (3, 5), (9, 4), (9, 4)],
+     32, None),                                          # dups, self-loops
+    (37, 1, _random_edges(37, 50, 1), 64, None),         # V % 8 != 0
+    (131, 32, _random_edges(131, 200, 2), 256, None),    # V % 128 != 0
+    (64, 32, _random_edges(64, 48, 3), 48, None),        # count == bucket
+    (64, 7, _random_edges(64, 64, 4), 64, None),         # count == bucket
+    (50, 32, _random_edges(50, 90, 5), 96, 16),          # grid of chunks
+    (50, 7, _random_edges(50, 37, 6), 96, 16),           # ... with empty ones
+])
+def test_lane_matmul_edges_matches_dense(v, q, edges, e_pad, chunk):
+    """The edge kernel (interpret), its "ref" twin and the dense
+    ``lane_matmul`` oracle on the packed matrix of the same edges agree
+    bit for bit; padding entries past ``count`` never reach a row."""
+    from repro.kernels.bitset_matmul import lane_matmul_edges
+    dst, src, count, x = _edge_case(v, q, edges, e_pad, seed=v + q)
+    kw = bitset.n_words(v)
+    a = np.zeros((v, kw), np.uint32)
+    if edges:
+        bitset.set_bits_np(a, (dst[:count],), src[:count])
+    x_k = np.zeros((kw * 32, q), np.uint32)
+    x_k[:v] = x
+    want = np.asarray(ref.lane_matmul_ref(jnp.asarray(a), jnp.asarray(x_k),
+                                          op="or"))
+    args = (jnp.asarray(dst), jnp.asarray(src), jnp.int32(count),
+            jnp.asarray(x))
+    got_ref = np.asarray(ops.frontier_step_edges(*args, n_rows=v,
+                                                 mode="ref"))
+    if chunk is None:
+        got = np.asarray(ops.frontier_step_edges(*args, n_rows=v,
+                                                 mode="interpret"))
+    else:
+        got = np.asarray(lane_matmul_edges(*args, n_rows=v, chunk=chunk,
+                                           interpret=True))
+    np.testing.assert_array_equal(got_ref, want)
+    np.testing.assert_array_equal(got, want)
+    if not edges:
+        assert not got.any()

@@ -20,7 +20,8 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.core import compressed, distributed, engine, graph as G
 from repro.kernels import block_sparse, pattern_filter
-from repro.kernels.bitset_matmul import bitset_matmul, lane_matmul
+from repro.kernels.bitset_matmul import (bitset_matmul, lane_matmul,
+                                         lane_matmul_edges)
 
 V = 8192
 KW = V // 32
@@ -69,6 +70,28 @@ def test_lane_matmul_compiles(one_chip, op, dtype):
     _compile(lambda a, x: lane_matmul(a, x, op=op, cap=(1 << 15) - 1),
              jax.ShapeDtypeStruct((V, KW), jnp.uint32, sharding=one_chip),
              jax.ShapeDtypeStruct((V, 64), dtype, sharding=one_chip))
+
+
+@pytest.mark.parametrize("e_pad", [4096, 6144, 8192, 12288])
+def test_lane_matmul_edges_compiles(one_chip, e_pad):
+    """Phase 2's class expansion as served at V=8192: 8 special labels
+    plus the neutral class under ``scan``, 32 jobs a chunk; 12288 edges
+    take two grid steps."""
+    classes, q = 9, 32
+
+    def expand(rows, cols, count, x):
+        def body(acc, op):
+            return acc | lane_matmul_edges(*op, x, n_rows=V), None
+        return jax.lax.scan(body, jnp.zeros_like(x), (rows, cols, count))[0]
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    compiled = _compile(expand, i32(classes, e_pad), i32(classes, e_pad),
+                        i32(classes),
+                        jax.ShapeDtypeStruct((V, q), jnp.uint32,
+                                             sharding=one_chip))
+    assert "%lane_matmul_edges" in compiled.as_text()
 
 
 @pytest.mark.parametrize("op", ["or", "min"])
